@@ -205,11 +205,11 @@ def _measure(quick: bool) -> dict:
 
 
 def run(device_counts=(1, 2, 8), *, quick: bool = False) -> list[dict]:
-    """Per-device-count query sweep (subprocess per count)."""
+    """Per-device-count query sweep (subprocess per count on the CPU)."""
     from benchmarks.serving import sweep_device_counts
 
     return sweep_device_counts("benchmarks.index_query", device_counts,
-                               quick=quick)
+                               _measure, quick=quick)
 
 
 if __name__ == "__main__":

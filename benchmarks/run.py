@@ -161,6 +161,9 @@ def main():
                          "cross-PR trajectory)")
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.json is None:
         args.json = ("experiments/benchmarks-quick.json" if args.quick
                      else "experiments/benchmarks.json")

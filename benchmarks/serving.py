@@ -153,7 +153,8 @@ def _measure(quick: bool) -> dict:
     cfg = registry.reduced_config("two-tower-retrieval")
     engine_stats = serve_engine(
         cfg, requests=32 if quick else 256,
-        candidates=(1 << 9) if quick else (1 << 16), record=False)
+        candidates=(1 << 9) if quick else (1 << 16), record=False,
+        n_devices=n_dev)
     out = {"devices": n_dev, "decode": decode_rows, "engine": engine_stats}
     if n_dev == 1:
         # once per sweep (the single-device process): the telemetry
@@ -162,15 +163,24 @@ def _measure(quick: bool) -> dict:
     return out
 
 
-def sweep_device_counts(module: str, device_counts, *,
+def sweep_device_counts(module: str, device_counts, measure_fn, *,
                         quick: bool = False) -> list[dict]:
     """Spawn ``python -m <module> --devices N`` per count; collect the JSON.
 
-    jax locks the host-platform device count at first init, so every count
-    needs its own process. Shared by the serving and index-query sweeps —
-    the target module's ``main()`` must accept ``--devices/--quick/--out``
-    and dump its measurement JSON to ``--out``.
+    jax locks the host-platform device count at first init, so on the CPU
+    every count needs its own process. Shared by the serving and
+    index-query sweeps — the target module's ``main()`` must accept
+    ``--devices/--quick/--out`` and dump its measurement JSON to ``--out``.
+
+    The forced host-device counts exist only on the CPU. On an accelerator
+    this process holds the chip as soon as it has touched JAX, and a child
+    that needs the chip would fail or hang: there the sweep runs
+    ``measure_fn(quick)`` once, in this process, on the devices attached.
     """
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return [measure_fn(quick)]
     rows = []
     env_base = {k: v for k, v in os.environ.items()}
     tag = module.rsplit(".", 1)[-1]
@@ -217,8 +227,8 @@ def sweep_main(run_fn, measure_fn):
 
 
 def run(device_counts=(1, 2, 8), *, quick: bool = False) -> list[dict]:
-    """Per-device-count serving sweep (subprocess per count)."""
-    return sweep_device_counts("benchmarks.serving", device_counts,
+    """Per-device-count serving sweep (subprocess per count on the CPU)."""
+    return sweep_device_counts("benchmarks.serving", device_counts, _measure,
                                quick=quick)
 
 

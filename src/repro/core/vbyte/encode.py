@@ -24,6 +24,7 @@ from dataclasses import dataclass
 import numpy as np
 
 MAX_BYTES_PER_INT = 5  # 32-bit integers need at most ceil(32/7) = 5 bytes
+_ROWS_PER_PASS = 4096  # payload rows per size-accounting pass (bounds memory)
 _LEN_THRESHOLDS = np.array([1 << 7, 1 << 14, 1 << 21, 1 << 28], dtype=np.uint64)
 _U32_MAX = 0xFFFFFFFF
 
@@ -128,17 +129,20 @@ class BlockedEncoding:
 
     @property
     def payload_bytes(self) -> int:
-        """Tight compressed size (excludes block padding): the paper's metric."""
-        return int(vbyte_lengths(self._encoded_values()).sum()) if self.n else 0
+        """Tight compressed size (excludes block padding): the paper's metric.
 
-    def _encoded_values(self) -> np.ndarray:
-        # re-derive gap/raw values from the payload for size accounting
-        from .ref import decode_stream_scalar  # local import to avoid cycle
-
-        out = []
-        for b in range(self.n_blocks):
-            out.append(decode_stream_scalar(self.payload[b], int(self.counts[b])))
-        return np.concatenate(out) if out else np.zeros(0, np.uint64)
+        Block ``b``'s integers fill its row from byte 0 up to and including
+        its ``counts[b]``-th terminator (a byte with the high bit clear), so
+        the bytes in use are those before that terminator, plus it.
+        """
+        total = 0
+        for r in range(0, self.n_blocks, _ROWS_PER_PASS):
+            rows = self.payload[r:r + _ROWS_PER_PASS]
+            counts = self.counts[r:r + _ROWS_PER_PASS].astype(np.int64)
+            ends = np.cumsum(rows < 0x80, axis=1, dtype=np.int64)
+            total += int((ends < counts[:, None]).sum())
+            total += int(np.count_nonzero(counts))
+        return total
 
     @property
     def device_bytes(self) -> int:

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .banded import onehot_gather
 from .kernel import prefix_sum_tile
 
 GATHER_BYTES = 5  # shift ≤ 7 bits + width ≤ 32 bits spans at most 5 bytes
@@ -78,18 +79,14 @@ def binpack_decode_tile(widths: jax.Array, data: jax.Array, counts: jax.Array,
     # the 5-byte window costs two MXU contractions instead of five
     b = data.astype(jnp.int32)
     d = [_shift_left_cols(b, k) for k in range(GATHER_BYTES)]
-    grp012 = (d[0] + (d[1] << 8) + (d[2] << 16)).astype(jnp.float32)  # < 2^24
-    grp34 = (d[3] + (d[4] << 8)).astype(jnp.float32)  # < 2^16
+    grp012 = d[0] + (d[1] << 8) + (d[2] << 16)  # < 2^24
+    grp34 = d[3] + (d[4] << 8)  # < 2^16
 
     # one-hot MXU gather: lo24[t,j] = grp012[t, byte0[t,j]] (rows have a
-    # single nonzero and operands < 2^24, so f32 accumulation is exact)
-    ivec = lax.broadcasted_iota(jnp.int32, (T, B, S), 2)
-    onehot = (byte0[:, :, None] == ivec).astype(jnp.float32)  # [T, B, S]
-    dnums = (((2,), (1,)), ((0,), (0,)))  # contract over S, batch over T
-    lo24 = lax.dot_general(onehot, grp012, dnums,
-                           preferred_element_type=jnp.float32).astype(jnp.int32)
-    hi16 = lax.dot_general(onehot, grp34, dnums,
-                           preferred_element_type=jnp.float32).astype(jnp.int32)
+    # single nonzero and operands < 2^24, so full-precision f32 products
+    # and accumulation are exact)
+    lo24 = onehot_gather(byte0, grp012).astype(jnp.int32)
+    hi16 = onehot_gather(byte0, grp34).astype(jnp.int32)
 
     # lo24 < 2^24 is non-negative (>> is logical); 24 - shift ∈ 17..24;
     # (1 << 31) - 1 wraps to 0x7FFFFFFF in int32 — still the right mask,

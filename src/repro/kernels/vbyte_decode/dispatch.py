@@ -49,7 +49,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.obs import counter_inc as _obs_counter_inc, trace as _obs_trace
@@ -226,14 +225,16 @@ def resolve_plan(plan, *, format: str, epilogue: str,
 # execution
 # ---------------------------------------------------------------------------
 def _decode_grid(operands: dict, *, format: str, block_size: int,
-                 differential: bool, plan: DecodePlan) -> jax.Array:
+                 differential: bool, plan: DecodePlan,
+                 interpret: bool | None = None) -> jax.Array:
     """Step-1 decode to the uint32 [n_blocks, block_size] grid."""
     if plan.path == "pallas":
         fn = {"vbyte": vbyte_decode_blocked,
               "streamvbyte": stream_vbyte_decode_blocked,
               "binpack": binpack_decode_blocked}[format]
         return fn(**operands, block_size=block_size, differential=differential,
-                  block_tile=plan.block_tile, chunk_width=plan.chunk)
+                  block_tile=plan.block_tile, chunk_width=plan.chunk,
+                  interpret=interpret)
     if plan.path == "ref":
         if format != "vbyte":
             raise ValueError(
@@ -297,7 +298,8 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
     ep = eplib.get_epilogue(epilogue)
     if epilogue == "stream":
         return _decode_grid(operands, format=format, block_size=block_size,
-                            differential=differential, plan=plan)
+                            differential=differential, plan=plan,
+                            interpret=interpret)
 
     if plan.path == "pallas" and plan.fused:
         # broadcast extras (tables) must be VMEM-resident per grid step;
@@ -312,6 +314,8 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
                 block_size=block_size, differential=differential,
                 block_tile=plan.block_tile, chunk_width=plan.chunk,
                 interpret=interpret)
+        _obs_counter_inc("decode_plan_downgrade_total", epilogue=epilogue,
+                         reason="vmem_broadcast_budget")
         plan = DecodePlan("pallas", fused=False, block_tile=plan.block_tile,
                           chunk=plan.chunk)
     if plan.path == "jnp" and plan.fused:
@@ -320,7 +324,8 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
                           chunk_width=plan.chunk)
     # unfused: decode grid, then the epilogue as a second dispatch
     grid = _decode_grid(operands, format=format, block_size=block_size,
-                        differential=differential, plan=plan)
+                        differential=differential, plan=plan,
+                        interpret=interpret)
     return _apply_only(grid, operands["counts"], extras, epilogue=epilogue)
 
 
@@ -391,10 +396,10 @@ def _build_sharded_fn(mesh, axes: tuple, format: str, epilogue: str,
     body = functools.partial(
         _execute, format=format, epilogue=epilogue, block_size=block_size,
         differential=differential, plan=plan, interpret=interpret)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda operands, extras: body(operands, extras),
         mesh=mesh, in_specs=(in_operands, in_extras), out_specs=out_specs,
-        check_rep=False))
+        check_vma=False))
 
 
 def decode(

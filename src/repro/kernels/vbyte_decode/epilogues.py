@@ -94,8 +94,9 @@ FORMAT_OPERANDS = {
 
 # ---------------------------------------------------------------------------
 # epilogue bodies — pure jnp on the decode-tile contract. Reductions are per
-# output element (axis-local), so tile-vs-grid leading dims don't change the
-# accumulation order: fused == unfused bit-exactly.
+# output element (axis-local), and the one float sum over the block axis
+# (bag_sum) runs in an explicit order, so tile-vs-grid leading dims don't
+# change the result: fused == unfused bit-exactly.
 # ---------------------------------------------------------------------------
 def _stream_apply(vals, valid):
     return vals
@@ -107,7 +108,14 @@ def _bag_sum_apply(vals, valid, *, table):
     vecs = jnp.take(table, ids.reshape(-1), axis=0, mode="clip")
     vecs = vecs.reshape(T, B, -1)
     vecs = jnp.where(valid[:, :, None], vecs, 0)
-    return vecs.sum(axis=1)  # [T, d]
+    # one fixed float accumulation order, slot 0 → B-1, on every path: a
+    # ``sum(axis=1)`` lets the compiler pick a tree per shape, and the
+    # [block_tile, B] kernel tile and the [n_blocks, B] grid then round
+    # differently
+    acc = vecs[:, 0]
+    for j in range(1, B):
+        acc = acc + vecs[:, j]
+    return acc  # [T, d]
 
 
 def _dot_score_apply(vals, valid, *, table, query):

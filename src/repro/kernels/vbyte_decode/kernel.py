@@ -1,6 +1,6 @@
 """Pallas TPU kernel: blocked Masked-VByte decode with fused differential sum.
 
-TPU-native realization of the paper's decoder (DESIGN.md §2). Per grid step a
+TPU-native realization of the paper's decoder (docs/kernels.md). Per grid step a
 (T, S)-byte VMEM tile (T blocks × S payload bytes — 8×640 = 5120 bytes,
 ~427× the paper's 12-byte unit, amortizing per-step overhead the way the
 paper's 48-byte mask pipeline amortizes pmovmskb latency) is decoded entirely
@@ -17,8 +17,9 @@ branch-free:
     pslldq/paddd doubling tree).
 
 32-bit exactness on an f32 MXU is preserved by splitting every 32-bit word
-into 16-bit halves before each matmul: per-output sums stay < 2^24 (f32-exact)
-and are recombined with wrap-around int32 adds (≡ mod 2^32, i.e. uint32).
+into 16-bit halves before each matmul: per-output sums stay < 2^24 (f32-exact
+at ``precision=HIGHEST``, which every matmul here passes) and are recombined
+with wrap-around int32 adds (≡ mod 2^32, i.e. uint32).
 
 ``chunk_width=W`` swaps the dense O(S²)+O(S·B) routing for the chunked
 banded scatter (``banded.py``): out_idx is monotone with increments ≤ 1,
@@ -37,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from .banded import (banded_scatter_u32, chunked_prefix, normalize_chunk_width,
-                     pad_cols)
+                     exact_dot, onehot_scatter, split_cols, strict_upper)
 
 
 def _shift_right(x: jax.Array, k: int) -> jax.Array:
@@ -48,11 +49,9 @@ def _shift_right(x: jax.Array, k: int) -> jax.Array:
 
 def _row_cumsum_exact_u32(x: jax.Array, incl_tri: jax.Array) -> jax.Array:
     """Inclusive row cumsum of int32 values, exact mod 2^32 via 16-bit split."""
-    lo = (x & 0xFFFF).astype(jnp.float32)
-    hi = ((x >> 16) & 0xFFFF).astype(jnp.float32)
-    lo_s = lax.dot(lo, incl_tri, preferred_element_type=jnp.float32).astype(jnp.int32)
-    hi_s = lax.dot(hi, incl_tri, preferred_element_type=jnp.float32).astype(jnp.int32)
-    return lo_s + (hi_s << 16)
+    lo = exact_dot(x & 0xFFFF, incl_tri)
+    hi = exact_dot((x >> 16) & 0xFFFF, incl_tri)
+    return lo + (hi << 16)
 
 
 def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
@@ -66,7 +65,7 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     from host-level code; every fused epilogue consumes this contract.
 
     ``chunk_width=None`` runs the dense O(S²)+O(S·B) routing (full
-    triangular prefix matmul + [T, S, B] one-hot scatter). An integer ``W``
+    triangular prefix matmul + [T, B, S] one-hot scatter). An integer ``W``
     selects the chunked banded-scatter routing (``banded.py``): out_idx is
     monotone and increments ≤1 per byte, so chunk ``c``'s bytes land only
     in slots ``[chunk_base[c], chunk_base[c]+W)`` — O(S·W) MACs, identical
@@ -93,43 +92,25 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     if chunk_width is None:
         # dense routing: exclusive prefix sum over the full byte axis
         # (out_idx[t,i] = #terminators < i) + full-width one-hot scatter
-        ii = lax.broadcasted_iota(jnp.int32, (S, S), 0)
-        jj = lax.broadcasted_iota(jnp.int32, (S, S), 1)
-        strict_tri = (ii < jj).astype(jnp.float32)  # [S,S], U[k,i]=1 iff k<i
-        out_idx = lax.dot(
-            end.astype(jnp.float32), strict_tri,
-            preferred_element_type=jnp.float32).astype(jnp.int32)
-
+        out_idx = exact_dot(end, strict_upper(S))
         keep = out_idx < counts  # [T,S] < [T,1]
         contrib = jnp.where(keep, contrib, 0)
-        out_idx = jnp.where(keep, out_idx, B - 1)  # clamp masked bytes
-
         # one-hot MXU scatter: out[t,j] = Σ_i [out_idx[t,i]==j]·contrib[t,i]
-        jvec = lax.broadcasted_iota(jnp.int32, (T, S, B), 2)
-        onehot = (out_idx[:, :, None] == jvec).astype(jnp.float32)  # [T,S,B]
-        dnums = (((1,), (1,)), ((0,), (0,)))  # contract over S, batch over T
-        lo = (contrib & 0xFFFF).astype(jnp.float32)
-        hi = ((contrib >> 16) & 0xFFFF).astype(jnp.float32)
-        lo_sum = lax.dot_general(onehot, lo, dnums,
-                                 preferred_element_type=jnp.float32)
-        hi_sum = lax.dot_general(onehot, hi, dnums,
-                                 preferred_element_type=jnp.float32)
-        out = lo_sum.astype(jnp.int32) + (hi_sum.astype(jnp.int32) << 16)
+        # (masked bytes carry zero, so where they route is irrelevant)
+        lo = onehot_scatter(out_idx, contrib & 0xFFFF, B)
+        hi = onehot_scatter(out_idx, (contrib >> 16) & 0xFFFF, B)
+        out = lo.astype(jnp.int32) + (hi.astype(jnp.int32) << 16)
     else:
         W = normalize_chunk_width(chunk_width, B)
         # chunked prefix: loc = #terminators earlier in the chunk (the
         # within-band slot, < W by construction), base = #terminators in
         # earlier chunks. Padding flags are zeros, so bases are unaffected.
-        end_p = pad_cols(end, W)  # [T, Sp]
-        Sp = end_p.shape[1]
-        nC = Sp // W
-        loc, base = chunked_prefix(end_p, W)
-        out_idx = (base[:, :, None] + loc).reshape(T, Sp)[:, :S]
-
-        keep = out_idx < counts  # [T,S] < [T,1]
-        contrib = jnp.where(keep, contrib, 0)
-        lo = pad_cols(contrib & 0xFFFF, W).reshape(T, nC, W)
-        hi = pad_cols((contrib >> 16) & 0xFFFF, W).reshape(T, nC, W)
+        loc, base = chunked_prefix(end, W)
+        lo, hi = [], []
+        for lc, bc, cc in zip(loc, base, split_cols(contrib, W)):
+            cc = jnp.where(bc + lc < counts, cc, 0)  # keep: out_idx < count
+            lo.append(cc & 0xFFFF)
+            hi.append((cc >> 16) & 0xFFFF)
         # banded one-hot scatter into W-slot bands + barrel-shift combine;
         # straddling integers recombine via the overlapped int32 band add
         out = banded_scatter_u32(loc, lo, hi, base, B)
@@ -144,7 +125,7 @@ def prefix_sum_tile(out: jax.Array, valid: jax.Array, bases: jax.Array) -> jax.A
     """Fused differential epilogue: inclusive row cumsum (mod 2^32) + bases.
 
     ``out`` int32 [T, B] gap values, ``bases`` int32 [T, 1] carry-in
-    (bitcast of uint32). Shared by both format kernels.
+    (bitcast of uint32). Shared by every format kernel.
     """
     B = out.shape[-1]
     kk = lax.broadcasted_iota(jnp.int32, (B, B), 0)

@@ -12,10 +12,10 @@ continuation-bit machinery entirely:
   * byte→integer routing is a strict-triangular f32 matmul prefix sum over
     the *lengths* (in the VByte kernel the same matmul runs over terminator
     flags — here the operand comes straight from the control stream),
-  * each data byte finds its owner by comparing its index against the start
-    offsets (branch-free rank computation), and its in-integer position is
-    ``i - start[owner]`` with the owner's start gathered by a one-hot MXU
-    matmul,
+  * each integer's end flag is scattered into byte space by a one-hot MXU
+    matmul; the owner of data byte ``i`` is the number of end flags before
+    it (a second triangular matmul) and its in-integer position has the
+    Masked-VByte closed form over the preceding flags,
   * reassembly reuses the 16-bit-split one-hot MXU scatter: lo halfword
     collects positions 0–1, hi halfword positions 2–3, recombined with a
     wrap-around int32 shift-add (≡ mod 2^32, i.e. uint32) — all per-output
@@ -26,8 +26,8 @@ All tensors live in VMEM; shapes are static; padding control codes are zeros
 (code 0 = length 1) so masking by ``count`` is load-bearing, as everywhere
 else in this repo.
 
-``chunk_width=W`` replaces the O(S·B) rank/gather/scatter routing above
-with the chunked banded scatter: per-integer end flags are banded into
+``chunk_width=W`` replaces the O(S·B) flag/scatter routing above with the
+chunked banded scatter: per-integer end flags are banded into
 byte space (a W-integer chunk spans ≤ 4W data bytes), after which the
 byte→integer machinery is exactly the Masked-VByte banded core — O(S·W)
 MACs, bit-identical output (docs/kernels.md §Banded chunked scatter).
@@ -42,10 +42,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from .banded import (banded_scatter_u32, chunked_prefix, normalize_chunk_width,
-                     pad_cols, place_bands)
+                     exact_dot, onehot_scatter, place_bands, split_cols,
+                     strict_upper)
 from .kernel import prefix_sum_tile
-
-MAX_BYTES_PER_INT = 4
 
 
 def _shift_right_fill(x: jax.Array, k: int, fill: int) -> jax.Array:
@@ -65,16 +64,16 @@ def stream_decode_tile(control: jax.Array, data: jax.Array, counts: jax.Array,
     ``kernel.decode_tile`` — the shared decode-tile core every fused
     epilogue plugs into.
 
-    ``chunk_width=None`` runs the dense routing: the full ``[T, S, B]``
-    owner-rank tensor (every data byte compared against every integer's
-    start) reused as a one-hot for the owner-start gather and the two
+    ``chunk_width=None`` runs the dense routing: per-integer end flags
+    scattered into byte space by a ``[T, S, B]`` one-hot, a full ``[S, S]``
+    triangular prefix for the byte owners, and the two ``[T, B, S]``
     scatter matmuls. An integer ``W`` selects the chunked banded routing:
     per-integer **end flags** are scattered into byte space through narrow
-    ``[T, ng, W, 4W]`` bands (an integer chunk of W integers spans ≤ 4W
-    data bytes), after which the byte→integer machinery is exactly the
-    Masked-VByte banded core — chunked prefix of the end flags, closed-form
-    in-integer positions, ``[T, nC, W, W]`` banded scatter. O(S·W) instead
-    of O(S·B), identical uint32 output bit-for-bit.
+    ``[T, 4W, W]`` one-hot bands per integer chunk (a chunk of W integers
+    spans ≤ 4W data bytes), after which the byte→integer machinery is
+    exactly the Masked-VByte banded core — chunked prefix of the end flags,
+    closed-form in-integer positions, ``[T, W, W]`` banded scatter per byte
+    chunk. O(S·W) instead of O(S·B), identical uint32 output bit-for-bit.
     """
     T, C = control.shape
     _, S = data.shape
@@ -89,9 +88,7 @@ def stream_decode_tile(control: jax.Array, data: jax.Array, counts: jax.Array,
         cc = lax.broadcasted_iota(jnp.int32, (C, B), 0)
         jj = lax.broadcasted_iota(jnp.int32, (C, B), 1)
         expand = (jj // 4 == cc).astype(jnp.float32)  # [C, B]
-        packed = lax.dot(
-            ctrl.astype(jnp.float32), expand,
-            preferred_element_type=jnp.float32).astype(jnp.int32)  # [T, B]
+        packed = exact_dot(ctrl, expand)  # [T, B]
     else:
         # banded core: the unpack is a static ×4 lane broadcast — zero MACs
         packed = jnp.broadcast_to(ctrl[:, :, None], (T, C, 4)).reshape(T, B)
@@ -102,66 +99,55 @@ def stream_decode_tile(control: jax.Array, data: jax.Array, counts: jax.Array,
     length = jnp.where(valid_int, code + 1, 0)
 
     if chunk_width is None:
-        out = _dense_stream_routing(data, length, valid_int, S, B, T)
+        out = _dense_stream_routing(data, length, counts, S, B)
     else:
         out = _banded_stream_routing(
-            data, length, valid_int, counts,
-            W=normalize_chunk_width(chunk_width, B), S=S, B=B, T=T)
+            data, length, counts,
+            W=normalize_chunk_width(chunk_width, B), S=S, B=B)
 
     out = jnp.where(valid_int, out, 0)
     return out, valid_int
 
 
-def _dense_stream_routing(data, length, valid_int, S, B, T):
-    """Dense O(S·B) routing: full rank tensor + one-hot gather/scatter."""
+def _dense_stream_routing(data, length, counts, S, B):
+    """Dense O(S·B) routing: end flags scattered into byte space, then the
+    Masked-VByte dense core (full-row prefix + [T, B, S] one-hot scatter)."""
     # start offset of every integer: exclusive prefix sum over lengths
     # (strict-triangular MXU matmul; sums ≤ 4·B ≪ 2^24, f32-exact)
-    kk = lax.broadcasted_iota(jnp.int32, (B, B), 0)
-    ll = lax.broadcasted_iota(jnp.int32, (B, B), 1)
-    strict_tri = (kk < ll).astype(jnp.float32)
-    starts = lax.dot(
-        length.astype(jnp.float32), strict_tri, preferred_element_type=jnp.float32
-    ).astype(jnp.int32)  # [T, B]
-    total = jnp.sum(length, axis=1, keepdims=True)  # [T, 1] valid data bytes
-
-    # owner of data byte i: rank of i among start offsets (branch-free).
-    # out_idx[t,i] = #{j : valid_int[t,j] and starts[t,j] <= i} - 1
-    ib = lax.broadcasted_iota(jnp.int32, (T, S, B), 1)
-    started = (starts[:, None, :] <= ib) & valid_int[:, None, :]
-    out_idx = jnp.sum(started.astype(jnp.int32), axis=2) - 1  # [T, S]
-
-    irow = lax.broadcasted_iota(jnp.int32, (T, S), 1)
-    valid_byte = irow < total  # padding bytes own nothing
-
-    # in-integer position: i - starts[owner], owner's start gathered by a
-    # one-hot MXU matmul (starts ≤ S ≤ a few thousand: f32-exact)
-    jvec = lax.broadcasted_iota(jnp.int32, (T, S, B), 2)
-    onehot = (out_idx[:, :, None] == jvec).astype(jnp.float32)  # [T, S, B]
-    dnums = (((2,), (1,)), ((0,), (0,)))  # contract over B, batch over T
-    owner_start = lax.dot_general(
-        onehot, starts.astype(jnp.float32), dnums,
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.int32)  # [T, S]
-    pos = jnp.clip(irow - owner_start, 0, MAX_BYTES_PER_INT - 1)
-
-    # contributions, split by 16-bit halfword before the MXU scatter:
-    # positions 0-1 build the low halfword, positions 2-3 the high one.
-    byte = data.astype(jnp.int32)
-    lo = jnp.where(valid_byte & (pos < 2), byte << (8 * pos), 0)
-    hi = jnp.where(valid_byte & (pos >= 2), byte << (8 * (pos - 2)), 0)
-
-    # one-hot MXU scatter: out[t,j] = Σ_i [out_idx[t,i]==j]·contrib[t,i]
-    sdnums = (((1,), (1,)), ((0,), (0,)))  # contract over S, batch over T
-    lo_sum = lax.dot_general(
-        onehot, lo.astype(jnp.float32), sdnums, preferred_element_type=jnp.float32
-    )
-    hi_sum = lax.dot_general(
-        onehot, hi.astype(jnp.float32), sdnums, preferred_element_type=jnp.float32
-    )
-    return lo_sum.astype(jnp.int32) + (hi_sum.astype(jnp.int32) << 16)  # [T, B]
+    starts = exact_dot(length, strict_upper(B))  # [T, B]
+    # end flag of integer j at byte starts[j] + length[j] - 1; invalid
+    # integers (length 0) carry no flag
+    ends = onehot_scatter(starts + length - 1, length > 0, S
+                          ).astype(jnp.int32)  # [T, S]
+    pos = _in_integer_position(ends)
+    # owner of byte i = #end flags strictly before i; bytes past the last
+    # valid end flag get out_idx == count ⇒ masked
+    out_idx = exact_dot(ends, strict_upper(S))  # [T, S]
+    keep = out_idx < counts
+    lo, hi = _halfword_contributions(data.astype(jnp.int32), pos, keep)
+    lo_sum = onehot_scatter(out_idx, lo, B).astype(jnp.int32)
+    hi_sum = onehot_scatter(out_idx, hi, B).astype(jnp.int32)
+    return lo_sum + (hi_sum << 16)  # [T, B]
 
 
-def _banded_stream_routing(data, length, valid_int, counts, *, W, S, B, T):
+def _in_integer_position(ends):
+    """Byte position inside its integer: closed form over preceding
+    non-end flags (lengths ≤ 4 ⇒ three terms); byte -1 counts as an end."""
+    e1 = _shift_right_fill(ends, 1, 1)
+    e2 = _shift_right_fill(ends, 2, 1)
+    e3 = _shift_right_fill(ends, 3, 1)
+    return (1 - e1) * (1 + (1 - e2) * (1 + (1 - e3)))
+
+
+def _halfword_contributions(byte, pos, keep):
+    """16-bit split before the MXU scatter: positions 0-1 build the low
+    halfword, positions 2-3 the high one."""
+    lo = jnp.where(keep & (pos < 2), byte << (8 * pos), 0)
+    hi = jnp.where(keep & (pos >= 2), byte << (8 * (pos - 2)), 0)
+    return lo, hi
+
+
+def _banded_stream_routing(data, length, counts, *, W, S, B):
     """Chunked O(S·W) routing via end flags in byte space.
 
     Stage 1 — integer-axis chunking: chunked prefix of the lengths gives
@@ -179,44 +165,32 @@ def _banded_stream_routing(data, length, valid_int, counts, *, W, S, B, T):
     """
     # integer starts via chunked prefix over the lengths (B axis, padded to
     # a chunk multiple; padding lengths are zero so starts stay == total)
-    len_p = pad_cols(length, W)  # [T, Bp]
-    Bp = len_p.shape[1]
-    ng = Bp // W
-    loc_l, base_l = chunked_prefix(len_p, W)
-    starts_p = (base_l[:, :, None] + loc_l).reshape(T, Bp)
-
-    # end flag of integer j sits at starts[j] + length[j] - 1; scatter the
-    # flags through [ng, W, 4W] bands anchored at each chunk's first start
-    end_pos = starts_p + len_p - 1  # [T, Bp]; invalid ints masked below
-    byte_base = starts_p.reshape(T, ng, W)[:, :, 0]  # [T, ng] anchors
-    local_end = end_pos.reshape(T, ng, W) - byte_base[:, :, None]
-    ovec = lax.broadcasted_iota(jnp.int32, (T, ng, W, 4 * W), 3)
-    is_end = ((local_end[:, :, :, None] == ovec)
-              & (len_p.reshape(T, ng, W)[:, :, :, None] > 0))
-    ends_band = jnp.sum(is_end.astype(jnp.int32), axis=2)  # [T, ng, 4W]
+    loc_l, base_l = chunked_prefix(length, W)
     Sp = S + ((-S) % W)
-    ends = place_bands(ends_band, byte_base, Sp)  # [T, Sp] end flags
+    bands, anchors = [], []
+    for lc, bc, len_c in zip(loc_l, base_l, split_cols(length, W)):
+        starts = bc + lc  # [T, W]
+        anchor = starts[:, :1]  # [T, 1] the chunk's first start
+        # end flag of integer j sits at starts[j] + length[j] - 1, inside
+        # the chunk's [4W] band; invalid ints (length 0) carry no flag
+        local_end = starts + len_c - 1 - anchor
+        bands.append(onehot_scatter(local_end, (len_c > 0), 4 * W)
+                     .astype(jnp.int32))  # [T, 4W]
+        anchors.append(anchor)
+    ends = place_bands(bands, anchors, Sp)  # [T, Sp] end flags
 
-    # in-integer position: closed form over preceding non-end flags
-    # (lengths ≤ 4 ⇒ three terms); byte -1 is treated as an end (fill=1)
-    e1 = _shift_right_fill(ends, 1, 1)
-    e2 = _shift_right_fill(ends, 2, 1)
-    e3 = _shift_right_fill(ends, 3, 1)
-    pos = (1 - e1) * (1 + (1 - e2) * (1 + (1 - e3)))  # [T, Sp]
-    pos = pos[:, :S]
+    pos = _in_integer_position(ends)  # [T, Sp]
 
     # owner of byte i = #end flags strictly before i (chunked prefix);
     # bytes past the last valid end flag get out_idx == count ⇒ masked
     loc_b, base_b = chunked_prefix(ends, W)
-    nC = Sp // W
-    out_idx = (base_b[:, :, None] + loc_b).reshape(T, Sp)[:, :S]
-    keep = out_idx < counts  # [T, S] < [T, 1]
-
     byte = data.astype(jnp.int32)
-    lo = jnp.where(keep & (pos < 2), byte << (8 * pos), 0)
-    hi = jnp.where(keep & (pos >= 2), byte << (8 * (pos - 2)), 0)
-    lo = pad_cols(lo, W).reshape(T, nC, W)
-    hi = pad_cols(hi, W).reshape(T, nC, W)
+    lo, hi = [], []
+    for lc, bc, pc, yc in zip(loc_b, base_b, split_cols(pos, W),
+                              split_cols(byte, W)):
+        lo_c, hi_c = _halfword_contributions(yc, pc, bc + lc < counts)
+        lo.append(lo_c)
+        hi.append(hi_c)
     return banded_scatter_u32(loc_b, lo, hi, base_b, B)
 
 

@@ -731,9 +731,27 @@ def write_metrics_out(tele, out_dir: str) -> dict:
     return paths
 
 
+def serving_mesh(n_devices: int):
+    """A ``data`` mesh over the first ``n_devices`` attached devices, or
+    None for one device. The count is the caller's choice: a host with more
+    chips attached does not silently turn a one-device run into a sharded
+    one."""
+    import numpy as np
+
+    import jax
+
+    devices = jax.devices()
+    if n_devices > len(devices):
+        raise ValueError(f"{n_devices} devices requested, {len(devices)} "
+                         "attached")
+    if n_devices <= 1:
+        return None
+    return jax.sharding.Mesh(np.array(devices[:n_devices]), ("data",))
+
+
 def serve_search(*, queries: int, group_k: int = 10, n_lists: int = 16,
                  top_k: int = 10, record: bool = True, seed: int = 0,
-                 metrics_out: str | None = None) -> dict:
+                 metrics_out: str | None = None, n_devices: int = 1) -> dict:
     """Build a synthetic posting-list index and drive a query workload.
 
     ``metrics_out=DIR`` installs a telemetry capture around the measured
@@ -742,8 +760,6 @@ def serve_search(*, queries: int, group_k: int = 10, n_lists: int = 16,
     into benchmarks.json as the ``observability`` section.
     """
     import numpy as np
-
-    import jax
 
     from repro.data.synthetic import posting_list_group, posting_tfs
     from repro.index import build_index
@@ -754,10 +770,9 @@ def serve_search(*, queries: int, group_k: int = 10, n_lists: int = 16,
         posting_list_group(rng, group_k, n_lists, universe=universe)))
     tfs = {t: posting_tfs(rng, len(v)) for t, v in lists.items()}
     index = build_index(lists, tfs=tfs, n_docs=universe)
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",)) if n_dev > 1 else None
+    mesh = serving_mesh(n_devices)
     print(f"index: {index.n_terms} terms, {index.n_postings} postings, "
-          f"{index.bits_per_int:.2f} bits/int over {n_dev} device(s)")
+          f"{index.bits_per_int:.2f} bits/int over {n_devices} device(s)")
 
     engine = SearchEngine(index, mesh=mesh, top_k=top_k)
     qs = search_queries(rng, index, queries)
@@ -804,7 +819,7 @@ def serve_search(*, queries: int, group_k: int = 10, n_lists: int = 16,
 def serve_search_degraded(*, queries: int = 32, group_k: int = 8,
                           n_lists: int = 16, n_shards: int = 8,
                           top_k: int = 10, record: bool = True,
-                          seed: int = 0) -> dict:
+                          seed: int = 0, n_devices: int = 1) -> dict:
     """CI degraded-serving smoke (docs/robustness.md).
 
     Builds a checksummed index served over ``n_shards`` logical shards,
@@ -817,8 +832,6 @@ def serve_search_degraded(*, queries: int = 32, group_k: int = 8,
     """
     import numpy as np
 
-    import jax
-
     from repro.data.synthetic import posting_list_group, posting_tfs
     from repro.index import QueryStats, build_index
 
@@ -828,8 +841,7 @@ def serve_search_degraded(*, queries: int = 32, group_k: int = 8,
         posting_list_group(rng, group_k, n_lists, universe=universe)))
     tfs = {t: posting_tfs(rng, len(v)) for t, v in lists.items()}
     index = build_index(lists, tfs=tfs, n_docs=universe, checksum=True)
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",)) if n_dev > 1 else None
+    mesh = serving_mesh(n_devices)
 
     sim = {"t": 0.0}  # injectable clock: the smoke is deterministic
 
@@ -840,7 +852,7 @@ def serve_search_degraded(*, queries: int = 32, group_k: int = 8,
     engine = SearchEngine(index, mesh=mesh, top_k=top_k, validate=True,
                           n_shards=n_shards, clock=clock)
     print(f"degraded smoke: {index.n_terms} terms over {n_shards} logical "
-          f"shards, {n_dev} device(s), validate=True "
+          f"shards, {n_devices} device(s), validate=True "
           f"(quarantined={engine.serve_stats['quarantined_terms']})")
     assert not engine.quarantined and not engine.bound_unsafe
 
@@ -901,7 +913,7 @@ def serve_search_degraded(*, queries: int = 32, group_k: int = 8,
     stats = {
         "n_queries": len(qs),
         "n_shards": n_shards,
-        "n_devices": n_dev,
+        "n_devices": n_devices,
         "degraded_responses": degraded,
         "healed_shards": engine.n_shards,
         **{k: v for k, v in engine.serve_stats.items()},
@@ -1186,7 +1198,8 @@ def record_benchmark(section: str, payload, path: str | None = None):
 
 
 def serve_engine(cfg, *, requests: int, candidates: int, top_k: int = 10,
-                 record: bool = True, seed: int = 0) -> dict:
+                 record: bool = True, seed: int = 0,
+                 n_devices: int = 1) -> dict:
     """Build the sharded compressed engine and drive a synthetic workload."""
     import numpy as np
 
@@ -1202,11 +1215,10 @@ def serve_engine(cfg, *, requests: int, candidates: int, top_k: int = 10,
     cands = np.sort(rng.choice(np.arange(1, cfg.n_items), n_cand,
                                replace=False)).astype(np.uint64)
     corpus = CompressedIntArray.encode(cands, differential=True)
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",)) if n_dev > 1 else None
+    mesh = serving_mesh(n_devices)
     print(f"corpus: {corpus.n} candidate ids, {corpus.bits_per_int:.2f} "
           f"bits/int ({corpus.compression_ratio:.2f}x vs uint32), "
-          f"{corpus.n_blocks} blocks over {n_dev} device(s)")
+          f"{corpus.n_blocks} blocks over {n_devices} device(s)")
 
     engine = ServingEngine(params, cfg, corpus, mesh=mesh, top_k=top_k)
     engine.warmup()
@@ -1240,7 +1252,9 @@ def main():
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host-platform devices (sharded engine)")
+                    help="serve on N devices (sharded engine when N > 1): "
+                         "the first N attached; on the CPU, N forced "
+                         "host-platform devices")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--candidates", type=int, default=1 << 16)
     ap.add_argument("--top-k", type=int, default=10)
@@ -1268,6 +1282,10 @@ def main():
         ).strip()
 
     # jax must initialize AFTER the device-count flag is set
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    n_devices = args.devices or 1
     if args.arch == "search":
         if args.ingest_smoke:
             serve_ingest_smoke(ops=max(args.requests, 50),
@@ -1276,11 +1294,12 @@ def main():
             return
         if args.degraded_smoke:
             serve_search_degraded(queries=args.requests, top_k=args.top_k,
-                                  record=not args.no_record)
+                                  record=not args.no_record,
+                                  n_devices=n_devices)
         else:
             serve_search(queries=args.requests, top_k=args.top_k,
                          record=not args.no_record,
-                         metrics_out=args.metrics_out)
+                         metrics_out=args.metrics_out, n_devices=n_devices)
         return
 
     from repro.distributed.api import activate_mesh
@@ -1296,7 +1315,7 @@ def main():
         if cfg.kind == "two_tower":
             serve_engine(cfg, requests=args.requests,
                          candidates=args.candidates, top_k=args.top_k,
-                         record=not args.no_record)
+                         record=not args.no_record, n_devices=n_devices)
         else:
             with activate_mesh(make_host_mesh()):
                 serve_recsys(cfg, args.batch)

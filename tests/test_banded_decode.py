@@ -302,13 +302,15 @@ def test_banded_sharded_parity(rng):
 # banded primitives + cost model
 # ---------------------------------------------------------------------------
 def test_place_bands_overlap_and_clip():
-    bands = jnp.asarray(np.array([[[1, 2, 0], [3, 4, 5]]], np.int32))
-    off = jnp.asarray(np.array([[1, 2]], np.int32))
+    bands = [jnp.asarray([[1, 2, 0]], jnp.int32),
+             jnp.asarray([[3, 4, 5]], jnp.int32)]
+    off = [jnp.asarray([[1]], jnp.int32), jnp.asarray([[2]], jnp.int32)]
     out = np.asarray(place_bands(bands, off, 6))
     # band 0 -> cols 1..3, band 1 -> cols 2..4 (overlap at 2..3 adds)
     np.testing.assert_array_equal(out, [[0, 1, 5, 4, 5, 0]])
     # offsets ≥ out_width push the whole band off the end
-    out2 = np.asarray(place_bands(bands, jnp.asarray([[6, 7]], jnp.int32), 6))
+    far = [jnp.asarray([[6]], jnp.int32), jnp.asarray([[7]], jnp.int32)]
+    out2 = np.asarray(place_bands(bands, far, 6))
     np.testing.assert_array_equal(out2, np.zeros((1, 6), np.int32))
 
 

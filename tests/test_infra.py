@@ -8,7 +8,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
@@ -152,8 +151,8 @@ def test_error_feedback_preserves_signal():
 def test_compressed_psum_single_device():
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
     x = jnp.asarray(np.random.default_rng(0).standard_normal(64), jnp.float32)
-    f = shard_map(lambda v: compressed_psum(v, "d"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda v: compressed_psum(v, "d"), mesh=mesh,
+                      in_specs=P(), out_specs=P())
     np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x), atol=2e-2)
 
 
@@ -207,3 +206,26 @@ def test_neighbor_sampler(rng):
         row = csr.indices[csr.indptr[d]:csr.indptr[d + 1]]
         assert s in row
     assert set(out["seed_ids"].tolist()) <= set(range(len(node_ids)))
+
+
+# -- compile cache placement ----------------------------------------------------
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if from_env:  # JAX reads the variable itself; nothing else is set
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:  # a fixed, gitignored directory at the checkout's root
+            assert got == compile_cache.DEFAULT_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == got
+            assert os.path.basename(got) == ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -1,0 +1,184 @@
+"""Compile rehearsal for TPU v5e: Mosaic must lower every decode core and
+the fused search epilogues at real tile sizes on one chip, and the sharded
+search decode on a 4-chip mesh.
+
+Interpret mode (every other kernel test here) runs the kernel bodies as
+plain jnp and cannot see what Mosaic refuses: an unsupported matmul form,
+a lane-splitting reshape, a primitive without a TPU lowering. These tests
+compile for a v5e chip that is described, not attached, so they run on a
+CPU-only machine and need no device. They compile and never execute, so
+they say nothing about results or speed.
+
+The plan compiled is the one ``dispatch.resolve_plan("auto")`` picks on a
+TPU: the tests steer ``jax.default_backend`` to ``"tpu"`` for plan
+resolution only, so a change of the TPU default plan is compiled here
+automatically.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.vbyte_decode import dispatch, epilogues, ops
+
+N_BLOCKS = 1024
+B = 128  # integers per block
+STRIDE = 640  # vbyte payload bytes per block (5 bytes × 128)
+DATA_STRIDE = 512  # streamvbyte / binpack data bytes per block
+W_STRIDE = 256  # impact-stream payload bytes per block (impacts < 2^8)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_plan(monkeypatch):
+    """Resolve ``plan="auto"`` as a TPU process would."""
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+
+    def resolve(fmt, epilogue="stream"):
+        return dispatch.resolve_plan("auto", format=fmt, epilogue=epilogue,
+                                     block_size=B)
+    return resolve
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _format_operands(sharding, fmt, prefix=""):
+    """Shapes of ``CompressedIntArray.device_operands()`` at real tiles."""
+    u8 = jnp.uint8
+    if fmt == "vbyte":
+        ops_ = {"payload": _spec(sharding, (N_BLOCKS, STRIDE), u8)}
+    elif fmt == "streamvbyte":
+        ops_ = {"control": _spec(sharding, (N_BLOCKS, B // 4), u8),
+                "data": _spec(sharding, (N_BLOCKS, DATA_STRIDE), u8)}
+    else:
+        ops_ = {"widths": _spec(sharding, (N_BLOCKS, 1), u8),
+                "data": _spec(sharding, (N_BLOCKS, DATA_STRIDE), u8)}
+    return {prefix + k: v for k, v in ops_.items()}
+
+
+def _meta(sharding):
+    return {"counts": _spec(sharding, (N_BLOCKS,), jnp.int32),
+            "bases": _spec(sharding, (N_BLOCKS,), jnp.uint32)}
+
+
+def _assert_mosaic_kernel(lowered):
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+DECODE_FNS = {"vbyte": ops.vbyte_decode_blocked,
+              "streamvbyte": ops.stream_vbyte_decode_blocked,
+              "binpack": ops.binpack_decode_blocked}
+
+
+@pytest.mark.parametrize("fmt", ["vbyte", "streamvbyte", "binpack"])
+def test_default_decode_plan_compiles(one_chip, tpu_plan, fmt):
+    plan = tpu_plan(fmt)
+    assert plan.path == "pallas" and plan.fused, plan
+    operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
+    lowered = DECODE_FNS[fmt].lower(
+        **operands, block_size=B, differential=True,
+        block_tile=plan.block_tile, chunk_width=plan.chunk, interpret=False)
+    _assert_mosaic_kernel(lowered)
+
+
+@pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("vbyte", 32),
+                                       ("streamvbyte", None)])
+def test_other_routing_widths_compile(one_chip, fmt, chunk):
+    # the dense cores decode every impact stream in the weighted
+    # epilogues; the other widths are autotune candidates
+    operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
+    lowered = DECODE_FNS[fmt].lower(
+        **operands, block_size=B, differential=True, block_tile=8,
+        chunk_width=chunk, interpret=False)
+    _assert_mosaic_kernel(lowered)
+
+
+def _search_extras(sharding, fmt, epilogue):
+    i32 = jnp.int32
+    if epilogue.endswith("_rows"):
+        extras = {"probe": _spec(sharding, (N_BLOCKS, 1), i32)}
+    else:
+        extras = {"probe": _spec(sharding, (1, 512), i32)}
+    if epilogue.startswith("bm25_accum"):
+        extras["impact"] = _spec(sharding, (1, 1), i32)
+    if epilogue.startswith("bm25_weighted"):
+        w = _format_operands(sharding, fmt, prefix="w_")
+        if fmt == "vbyte":
+            w["w_payload"] = _spec(sharding, (N_BLOCKS, W_STRIDE), jnp.uint8)
+        extras.update(w)
+    return extras
+
+
+@pytest.mark.parametrize("epilogue", ["membership_rows", "bm25_accum_rows",
+                                      "bm25_weighted_rows", "membership",
+                                      "bm25_accum", "bm25_weighted"])
+@pytest.mark.parametrize("fmt", ["vbyte", "binpack"])
+def test_fused_search_epilogue_compiles(one_chip, tpu_plan, fmt, epilogue):
+    plan = tpu_plan(fmt, epilogue)
+    assert plan.path == "pallas" and plan.fused, plan
+    operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
+    lowered = epilogues.fused_decode.lower(
+        operands, _search_extras(one_chip, fmt, epilogue), format=fmt,
+        epilogue=epilogue, block_size=B, differential=True,
+        block_tile=plan.block_tile, chunk_width=plan.chunk, interpret=False)
+    _assert_mosaic_kernel(lowered)
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "collective-permute",
+               "reduce-scatter")
+
+
+@pytest.mark.parametrize("epilogue", ["stream", "membership",
+                                      "bm25_weighted"])
+def test_sharded_search_decode_compiles(topo, tpu_plan, epilogue):
+    # SearchEngine(mesh=...) decodes block-sharded terms under shard_map:
+    # on a 4-chip data mesh every device must run its own Mosaic kernel on
+    # its own blocks, with no collective in the program
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    blk = NamedSharding(mesh, P("data", None))
+    row = NamedSharding(mesh, P("data"))
+    operands = {**_format_operands(blk, "vbyte"),
+                "counts": _spec(row, (N_BLOCKS,), jnp.int32),
+                "bases": _spec(row, (N_BLOCKS,), jnp.uint32)}
+    extras = {}
+    if epilogue != "stream":
+        extras["probe"] = _spec(NamedSharding(mesh, P()), (1, 512), jnp.int32)
+    if epilogue == "bm25_weighted":
+        extras["w_payload"] = _spec(blk, (N_BLOCKS, W_STRIDE), jnp.uint8)
+    plan = tpu_plan("vbyte", epilogue)
+    fn = dispatch._build_sharded_fn(
+        mesh, ("data",), "vbyte", epilogue, B, True, plan, False, False,
+        tuple(sorted(extras)))
+    text = fn.lower(operands, extras).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [c for c in COLLECTIVES if c in text]

@@ -117,6 +117,18 @@ def test_prop_differential_roundtrip():
         assert np.array_equal(arr.decode().astype(np.uint64), vals), case
 
 
+@pytest.mark.parametrize("block_size", [8, 32, 128])
+def test_prop_payload_bytes_is_tight_size(block_size):
+    # the vectorized size accounting equals the per-block scalar decode's
+    for case, vals in u32_cases(n_cases=30, max_len=600):
+        enc = CompressedIntArray.encode(vals, block_size=block_size).enc
+        want = sum(int(venc.vbyte_lengths(vref.decode_stream_scalar(
+            enc.payload[b], int(enc.counts[b]))).sum())
+            for b in range(enc.n_blocks))
+        assert enc.payload_bytes == want == venc.vbyte_lengths(vals).sum(), \
+            case
+
+
 def test_prop_length_formula(rng):
     # every byte-length threshold (±1 via BOUNDARY_VALUES) plus random draws
     samples = np.concatenate([
