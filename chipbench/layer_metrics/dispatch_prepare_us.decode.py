@@ -1,0 +1,11 @@
+"""Dispatch layer: mean host duration of the ``decode.prepare`` span per
+``dispatch.decode`` call, in us: unwrapping the operands, epilogue and
+operand checks, plan resolution and mesh detection, before the launch."""
+from chipbench.spans import spans_named
+
+
+def read(ctx):
+    spans = spans_named(ctx.spans, "decode.prepare")
+    if not spans:
+        return None
+    return sum(s["dur"] for s in spans) / len(spans) * 1e6

@@ -74,6 +74,22 @@ def normalize_chunk_width(chunk_width: int, block_size: int) -> int:
     return W
 
 
+def kernel_name(format: str, chunk_width: int | None,
+                epilogue: str | None = None) -> str:
+    """Stable name of a decode ``pallas_call``: its format, its fused
+    epilogue if any, and its routing core (``dense``, ``banded_w<W>``, or
+    ``gather`` for binpack, which has no chunk axis), e.g.
+    ``vbyte_decode_banded_w64`` or ``vbyte_fused_bag_sum_banded_w64``."""
+    if format == "binpack":
+        core = "gather"
+    elif chunk_width is None:
+        core = "dense"
+    else:
+        core = f"banded_w{int(chunk_width)}"
+    stage = "decode" if epilogue is None else f"fused_{epilogue}"
+    return f"{format}_{stage}_{core}"
+
+
 def pad_cols(x: jax.Array, multiple: int) -> jax.Array:
     """Zero-pad the last axis up to a multiple (static concatenate only)."""
     S = x.shape[-1]

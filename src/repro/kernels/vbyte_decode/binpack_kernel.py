@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .banded import onehot_gather
+from .banded import kernel_name, onehot_gather
 from .kernel import prefix_sum_tile
 
 GATHER_BYTES = 5  # shift ≤ 7 bits + width ≤ 32 bits spans at most 5 bytes
@@ -148,4 +148,5 @@ def binpack_decode_blocked_pallas(
         out_specs=pl.BlockSpec((block_tile, block_size), lambda g: (g, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.int32),
         interpret=interpret,
+        name=kernel_name("binpack", chunk_width),
     )(widths, data, counts, bases)

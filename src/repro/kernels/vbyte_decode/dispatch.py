@@ -430,61 +430,80 @@ def decode(
     ``shard_map`` — block-parallel, no cross-device decode traffic.
     ``plan="sharded"`` forces that path and raises if operands aren't
     sharded.
+
+    Traced as one ``decode`` span over the whole call with two children:
+    ``decode.prepare`` (operand unwrapping, checks, plan resolution, mesh
+    detection) and ``decode.launch`` (the call into the jitted program, up
+    to its return of the not-yet-ready output).
     """
-    from repro.core.compressed_array import CompressedIntArray
+    with _obs_trace("decode") as span:
+        with _obs_trace("decode.prepare"):
+            from repro.core.compressed_array import CompressedIntArray
 
-    if isinstance(operands, CompressedIntArray):
-        arr = operands
-        operands = arr.device_operands()
-        format = arr.format if format is None else format
-        block_size = arr.block_size if block_size is None else block_size
-        differential = (arr.differential if differential is None
-                        else differential)
-    if format is None or block_size is None or differential is None:
-        raise ValueError(
-            "format=/block_size=/differential= are required when operands "
-            "are a raw dict (pass a CompressedIntArray to omit them)")
-    if format not in eplib.FORMAT_OPERANDS:
-        raise ValueError(f"unknown format {format!r}; expected one of "
-                         f"{tuple(eplib.FORMAT_OPERANDS)}")
-    ep = eplib.get_epilogue(epilogue)
-    extras = dict(epilogue_operands or {})
-    ep.check(differential, extras)
-    force_sharded = plan == "sharded"
-    p = resolve_plan("auto" if force_sharded else plan, format=format,
-                     epilogue=epilogue, block_size=block_size)
+            if isinstance(operands, CompressedIntArray):
+                arr = operands
+                operands = arr.device_operands()
+                format = arr.format if format is None else format
+                block_size = (arr.block_size if block_size is None
+                              else block_size)
+                differential = (arr.differential if differential is None
+                                else differential)
+            if format is None or block_size is None or differential is None:
+                raise ValueError(
+                    "format=/block_size=/differential= are required when "
+                    "operands are a raw dict (pass a CompressedIntArray to "
+                    "omit them)")
+            if format not in eplib.FORMAT_OPERANDS:
+                raise ValueError(f"unknown format {format!r}; expected one "
+                                 f"of {tuple(eplib.FORMAT_OPERANDS)}")
+            ep = eplib.get_epilogue(epilogue)
+            extras = dict(epilogue_operands or {})
+            ep.check(differential, extras)
+            force_sharded = plan == "sharded"
+            p = resolve_plan("auto" if force_sharded else plan, format=format,
+                             epilogue=epilogue, block_size=block_size)
 
-    fmt_keys = eplib.FORMAT_OPERANDS[format] + ("counts", "bases")
-    missing = [k for k in fmt_keys if k not in operands]
-    if missing:
-        raise ValueError(f"format {format!r} operands missing {missing}")
-    nb = operands[fmt_keys[0]].shape[0]
-    operands = {k: operands[k] for k in fmt_keys}
-    operands["counts"] = normalize_block_meta("counts", operands["counts"], nb)
-    operands["bases"] = normalize_block_meta("bases", operands["bases"], nb)
+            fmt_keys = eplib.FORMAT_OPERANDS[format] + ("counts", "bases")
+            missing = [k for k in fmt_keys if k not in operands]
+            if missing:
+                raise ValueError(
+                    f"format {format!r} operands missing {missing}")
+            nb = operands[fmt_keys[0]].shape[0]
+            operands = {k: operands[k] for k in fmt_keys}
+            operands["counts"] = normalize_block_meta(
+                "counts", operands["counts"], nb)
+            operands["bases"] = normalize_block_meta(
+                "bases", operands["bases"], nb)
 
-    mesh_axes = operand_mesh_axes(operands)
-    if force_sharded and mesh_axes is None:
-        raise ValueError(
-            "plan='sharded' requires operands whose block dimension is "
-            "sharded over a >1-device mesh axis — use "
-            "CompressedIntArray.shard(mesh, axis=...) first")
-    _obs_counter_inc("decode_calls_total", plan=p.label, format=format,
-                     epilogue=epilogue)
-    with _obs_trace("decode", format=format, plan=p.label, epilogue=epilogue,
-                    blocks=int(nb), chunk=p.chunk,
-                    sharded=mesh_axes is not None):
-        if mesh_axes is not None:
-            mesh, axes = mesh_axes
-            q = extras["query"] if epilogue == "dot_score" else None
-            multi_query = bool(q is not None and q.size // q.shape[-1] > 1)
-            fn = _build_sharded_fn(mesh, axes, format, epilogue, block_size,
-                                   differential, p, interpret, multi_query,
-                                   tuple(sorted(extras)))
-            return fn(operands, extras)
-        return _execute(operands, extras, format=format, epilogue=epilogue,
-                        block_size=block_size, differential=differential,
-                        plan=p, interpret=interpret)
+            mesh_axes = operand_mesh_axes(operands)
+            if mesh_axes is not None:
+                mesh, axes = mesh_axes
+                q = extras["query"] if epilogue == "dot_score" else None
+                multi_query = bool(q is not None
+                                   and q.size // q.shape[-1] > 1)
+                launch = functools.partial(
+                    _build_sharded_fn(mesh, axes, format, epilogue,
+                                      block_size, differential, p, interpret,
+                                      multi_query, tuple(sorted(extras))),
+                    operands, extras)
+            elif force_sharded:
+                raise ValueError(
+                    "plan='sharded' requires operands whose block dimension "
+                    "is sharded over a >1-device mesh axis — use "
+                    "CompressedIntArray.shard(mesh, axis=...) first")
+            else:
+                launch = functools.partial(
+                    _execute, operands, extras, format=format,
+                    epilogue=epilogue, block_size=block_size,
+                    differential=differential, plan=p, interpret=interpret)
+            if span:
+                span.set(format=format, plan=p.label, epilogue=epilogue,
+                         blocks=int(nb), chunk=p.chunk,
+                         sharded=mesh_axes is not None)
+                _obs_counter_inc("decode_calls_total", plan=p.label,
+                                 format=format, epilogue=epilogue)
+        with _obs_trace("decode.launch"):
+            return launch()
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .banded import kernel_name
 from .binpack_kernel import binpack_decode_tile
 from .kernel import decode_tile, prefix_sum_tile
 from .stream_kernel import stream_decode_tile
@@ -455,6 +456,7 @@ def fused_decode_pallas(
         out_specs=list(out_specs) if multi else out_specs,
         out_shape=list(out_shape) if multi else out_shape,
         interpret=interpret,
+        name=kernel_name(format, chunk_width, epilogue),
     )(*fmt_arrays, counts, bases, *(extras[k] for k in extra_names))
 
 

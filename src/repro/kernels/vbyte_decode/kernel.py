@@ -37,8 +37,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .banded import (banded_scatter_u32, chunked_prefix, normalize_chunk_width,
-                     exact_dot, onehot_scatter, split_cols, strict_upper)
+from .banded import (banded_scatter_u32, chunked_prefix, exact_dot,
+                     kernel_name, normalize_chunk_width, onehot_scatter,
+                     split_cols, strict_upper)
 
 
 def _shift_right(x: jax.Array, k: int) -> jax.Array:
@@ -176,4 +177,5 @@ def decode_blocked_pallas(
         out_specs=pl.BlockSpec((block_tile, block_size), lambda g: (g, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.int32),
         interpret=interpret,
+        name=kernel_name("vbyte", chunk_width),
     )(payload, counts, bases)

@@ -41,9 +41,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .banded import (banded_scatter_u32, chunked_prefix, normalize_chunk_width,
-                     exact_dot, onehot_scatter, place_bands, split_cols,
-                     strict_upper)
+from .banded import (banded_scatter_u32, chunked_prefix, exact_dot,
+                     kernel_name, normalize_chunk_width, onehot_scatter,
+                     place_bands, split_cols, strict_upper)
 from .kernel import prefix_sum_tile
 
 
@@ -241,4 +241,5 @@ def stream_decode_blocked_pallas(
         out_specs=pl.BlockSpec((block_tile, block_size), lambda g: (g, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.int32),
         interpret=interpret,
+        name=kernel_name("streamvbyte", chunk_width),
     )(control, data, counts, bases)

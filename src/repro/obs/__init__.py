@@ -39,8 +39,6 @@ from .trace import (  # noqa: F401
     Telemetry,
     Tracer,
     counter_inc,
-    current,
-    gauge_set,
     histogram_observe,
     install,
     installed,

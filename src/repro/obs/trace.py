@@ -8,9 +8,9 @@ decoded/skipped/pruned, epilogue name — set at open time or via
 ``span.set(...)`` as counts become known.
 
 **Null fast path.** The hot decode/serving code calls :func:`trace` and the
-``counter_inc``/``gauge_set``/``histogram_observe`` helpers unconditionally.
-With nothing installed these cost one module-global read and a ``None``
-check; :func:`trace` returns the shared :data:`NULL_SPAN` singleton, so the
+``counter_inc``/``histogram_observe`` helpers unconditionally. With
+nothing installed these cost one module-global read and a ``None`` check;
+:func:`trace` returns the shared :data:`NULL_SPAN` singleton, so the
 clean path allocates no span objects and stays bit-exact. Everything
 activates only under :func:`install`, which flips the single module global::
 
@@ -171,10 +171,6 @@ class Tracer:
              "span_id": span.span_id, "trace_id": span.trace_id,
              "attrs": attrs})
 
-    def current(self) -> Span | _NullSpan:
-        st = getattr(self._tls, "stack", None)
-        return st[-1] if st else NULL_SPAN
-
     # -- queries -------------------------------------------------------------
     def durations(self, name: str) -> list[float]:
         """Durations (seconds) of every finished span with this name."""
@@ -266,24 +262,10 @@ def trace(name: str, **attrs):
     return Span(t.tracer, name, attrs)
 
 
-def current():
-    """The innermost open span on this thread (NULL_SPAN when off/idle)."""
-    t = _ACTIVE
-    if t is None:
-        return NULL_SPAN
-    return t.tracer.current()
-
-
 def counter_inc(name: str, n=1, **labels):
     t = _ACTIVE
     if t is not None:
         t.registry.counter(name, **labels).inc(n)
-
-
-def gauge_set(name: str, v, **labels):
-    t = _ACTIVE
-    if t is not None:
-        t.registry.gauge(name, **labels).set(v)
 
 
 def histogram_observe(name: str, v, **labels):

@@ -1,14 +1,19 @@
-"""Dispatch layer: plan resolution, the persisted autotune cache, and the
-counts/bases shape contract at the ops boundary."""
+"""Dispatch layer: plan resolution, the persisted autotune cache, the
+counts/bases shape contract at the ops boundary, the spans of a traced
+call, and the names of the Pallas kernels it launches."""
+import itertools
 import json
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import CompressedIntArray
 from repro.kernels.vbyte_decode import dispatch, normalize_block_meta
+from repro.kernels.vbyte_decode.banded import kernel_name
 from repro.kernels.vbyte_decode.dispatch import DecodePlan
 
 
@@ -165,3 +170,98 @@ def test_auto_plan_decodes_correctly(rng):
                                         differential=True)
         out = arr.decode(plan="auto")
         np.testing.assert_array_equal(out.astype(np.uint64), vals)
+
+
+# ---------------------------------------------------------------------------
+# spans of a traced call
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def step_clock_telemetry():
+    """Telemetry whose clock advances by one on every read, so span bounds
+    are exact and distinct."""
+    ticks = itertools.count()
+    return obs.Telemetry(clock=lambda: float(next(ticks)))
+
+
+def _ends(s):
+    return s["ts"], s["ts"] + s["dur"]
+
+
+@pytest.mark.parametrize("plan", ["jnp", "kernel"])
+def test_decode_span_covers_prepare_then_launch(rng, plan,
+                                                step_clock_telemetry):
+    vals = np.sort(rng.integers(0, 2**20, 300)).astype(np.uint64)
+    arr = CompressedIntArray.encode(vals, block_size=128, differential=True)
+    want = arr.decode(plan=plan)  # compiles outside the capture
+    with obs.install(step_clock_telemetry):
+        out = dispatch.decode(arr, plan=plan)
+    np.testing.assert_array_equal(
+        np.asarray(out).reshape(-1)[: arr.n].astype(np.uint32), want)
+
+    spans = step_clock_telemetry.tracer.spans
+    assert [s["name"] for s in spans] == ["decode.prepare", "decode.launch",
+                                          "decode"]  # in closing order
+    prepare, launch, parent = spans
+    assert parent["parent_id"] is None
+    assert prepare["parent_id"] == launch["parent_id"] == parent["span_id"]
+    p0, p1 = _ends(parent)
+    a0, a1 = _ends(prepare)
+    b0, b1 = _ends(launch)
+    assert p0 < a0 < a1 < b0 < b1 < p1  # inside the parent, no overlap
+    p = dispatch.resolve_plan(plan, format="vbyte", epilogue="stream",
+                              block_size=128)
+    assert parent["attrs"] == {"format": "vbyte", "plan": p.label,
+                               "epilogue": "stream", "blocks": arr.n_blocks,
+                               "chunk": p.chunk, "sharded": False}
+    assert prepare["attrs"] == launch["attrs"] == {}
+    counters = step_clock_telemetry.registry.snapshot()["metrics"]
+    assert [k for k in counters if k.startswith("decode_calls_total")] == [
+        f"decode_calls_total{{epilogue=stream,format=vbyte,plan={p.label}}}"]
+
+
+def test_decode_refused_in_prepare_records_no_launch(rng,
+                                                     step_clock_telemetry):
+    vals = np.sort(rng.integers(0, 2**20, 200)).astype(np.uint64)
+    ops = dict(CompressedIntArray.encode(vals, differential=True)
+               .device_operands())
+    del ops["bases"]
+    with obs.install(step_clock_telemetry):
+        with pytest.raises(ValueError, match="missing"):
+            dispatch.decode(ops, format="vbyte", block_size=128,
+                            differential=True, plan="jnp")
+    spans = step_clock_telemetry.tracer.spans
+    assert [s["name"] for s in spans] == ["decode.prepare", "decode"]
+    assert all(s["attrs"] == {"error": "ValueError"} for s in spans)
+    assert not step_clock_telemetry.registry.snapshot()["metrics"]
+
+
+# ---------------------------------------------------------------------------
+# kernel names
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("fmt,chunk,epilogue,want", [
+    ("vbyte", 64, None, "vbyte_decode_banded_w64"),
+    ("vbyte", None, None, "vbyte_decode_dense"),
+    ("streamvbyte", 32, None, "streamvbyte_decode_banded_w32"),
+    ("binpack", None, None, "binpack_decode_gather"),
+    ("vbyte", 64, "bag_sum", "vbyte_fused_bag_sum_banded_w64"),
+    ("binpack", None, "membership", "binpack_fused_membership_gather"),
+])
+def test_pallas_call_carries_kernel_name(rng, fmt, chunk, epilogue, want):
+    """Each decode ``pallas_call`` is named for its format, its fused
+    epilogue and its routing core; binpack ignores the chunk width."""
+    vals = np.sort(rng.integers(0, 512, 64)).astype(np.uint64)
+    arr = CompressedIntArray.encode(vals, format=fmt, block_size=128,
+                                    differential=True)
+    extras = {}
+    if epilogue == "bag_sum":
+        extras = {"table": jnp.ones((512, 8), jnp.float32)}
+    elif epilogue == "membership":
+        extras = {"probe": jnp.asarray([[3, 5]], jnp.int32)}
+    assert kernel_name(fmt, 16 if fmt == "binpack" else chunk,
+                       epilogue) == want
+    plan = DecodePlan("pallas", epilogue is not None, chunk=chunk)
+    jaxpr = jax.make_jaxpr(lambda ops: dispatch.decode(
+        ops, format=fmt, block_size=128, differential=True,
+        epilogue=epilogue or "stream", epilogue_operands=extras, plan=plan,
+        interpret=True))(arr.device_operands())
+    assert want in str(jaxpr)
