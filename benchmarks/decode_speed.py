@@ -206,10 +206,12 @@ def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
     The tracked decode-kernel perf trajectory (``--only decode``): for each
     format and chunk width the SAME tile-core code that runs inside the
     Pallas kernels is jitted over the full ``[n_blocks, S]`` grid (pure
-    jnp — XLA-CPU here, XLA-TPU on device), timed against the dense core
-    (``chunk_width=None``, the pre-banded baseline), and paired with the
-    modeled routing MACs / VMEM bytes of one ``[8, S]`` kernel tile
-    (``banded.routing_cost``). Pallas interpret-mode rows are appended at
+    jnp — XLA-CPU here, XLA-TPU on device), timed against the unchunked
+    core (``chunk_width=None``: compaction for vbyte, dense for the other
+    formats), and paired with the modeled routing MACs / VMEM bytes of one
+    ``[8, S]`` kernel tile (``banded.routing_cost``, whose ``W=None`` row
+    models the dense core, not vbyte's compaction). Pallas interpret-mode
+    rows are appended at
     a tiny size for coverage and tagged ``interpret: true`` — interpret
     wall time is a correctness artifact, not a perf number, and
     ``benchmarks/report.py`` excludes those rows from headline tables.

@@ -1,10 +1,15 @@
-"""Routing primitives shared by the decode-tile cores.
+"""MXU routing primitives of the decode-tile cores.
 
-The dense decode cores route bytes to output slots with a ``[T, B, S]``
-one-hot (every byte against every output) and recover ``out_idx`` with a
-full ``[S, S]`` triangular matmul — O(S·B) and O(S²) work for a job the
-paper does in O(bytes) with pshufb. The structural fact that makes routing
-cheap is the **chunk-band invariant**:
+The banded core is Stream VByte's default routing; Masked VByte takes it
+only by an explicit plan (``plan="banded"`` or a ``chunk`` width), since
+its default routes by VPU compaction with no matmul (``kernel.py``,
+docs/kernels.md §Compaction routing). Binpack uses :func:`onehot_gather`.
+
+A dense decode core (Stream VByte's unchunked one) routes bytes to output
+slots with a ``[T, B, S]`` one-hot (every byte against every output) and
+recovers ``out_idx`` with a full ``[S, S]`` triangular matmul — O(S·B)
+and O(S²) work for a job the paper does in O(bytes) with pshufb. The
+structural fact that makes routing cheap is the **chunk-band invariant**:
 
     ``out_idx`` is monotone non-decreasing along the byte axis and
     increments by at most 1 per byte, so the bytes of chunk ``c`` (a run of
@@ -77,13 +82,14 @@ def normalize_chunk_width(chunk_width: int, block_size: int) -> int:
 def kernel_name(format: str, chunk_width: int | None,
                 epilogue: str | None = None) -> str:
     """Stable name of a decode ``pallas_call``: its format, its fused
-    epilogue if any, and its routing core (``dense``, ``banded_w<W>``, or
-    ``gather`` for binpack, which has no chunk axis), e.g.
-    ``vbyte_decode_banded_w64`` or ``vbyte_fused_bag_sum_banded_w64``."""
+    epilogue if any, and its routing core (``banded_w<W>``; unchunked,
+    ``compact`` for vbyte and ``dense`` for streamvbyte; ``gather`` for
+    binpack, which has no chunk axis), e.g. ``vbyte_decode_compact`` or
+    ``vbyte_fused_bag_sum_banded_w64``."""
     if format == "binpack":
         core = "gather"
     elif chunk_width is None:
-        core = "dense"
+        core = "compact" if format == "vbyte" else "dense"
     else:
         core = f"banded_w{int(chunk_width)}"
     stage = "decode" if epilogue is None else f"fused_{epilogue}"

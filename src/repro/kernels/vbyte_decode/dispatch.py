@@ -83,9 +83,10 @@ VMEM_BROADCAST_BUDGET = 4 << 20
 class DecodePlan:
     """One concrete decode execution plan (see module docstring).
 
-    ``chunk`` is the banded-scatter chunk width W: ``None`` runs the dense
-    O(S·B) routing, an integer W the chunked O(S·W) routing (see
-    ``banded.py``). On the Pallas path it selects the banded tile cores;
+    ``chunk`` is the banded-scatter chunk width W: ``None`` runs the
+    format's unchunked routing (VPU compaction for vbyte, the dense O(S·B)
+    one-hot for streamvbyte), an integer W the chunked O(S·W) MXU routing
+    (see ``banded.py``). On the Pallas path it selects the banded tile cores;
     on the jnp path the chunked prefix decomposition of the vectorized
     decoders. Both produce bit-identical uint32 grids, so the axis is a
     pure perf knob — which is why it lives on the autotuned plan.
@@ -94,7 +95,7 @@ class DecodePlan:
     path: str  # "pallas" | "jnp" | "ref" (gather-lowered; GSPMD-friendly)
     fused: bool = True
     block_tile: int = 8
-    chunk: int | None = None  # banded-scatter chunk width W (None = dense)
+    chunk: int | None = None  # W; None = the format's unchunked route
 
     def __post_init__(self):
         if self.path not in ("pallas", "jnp", "ref"):
@@ -157,11 +158,16 @@ def load_cache(path: str | None = None, *, reload: bool = False) -> dict:
     return _CACHE
 
 
-# per-format default banded chunk width: the smallest W that clears the
-# ≥4x modeled routing-MAC reduction at default shapes without shrinking
-# the MXU tiles below usefulness (docs/kernels.md §Banded chunked scatter).
-# binpack has no length scan — the chunk axis doesn't exist for it.
-DEFAULT_CHUNK = {"vbyte": 64, "streamvbyte": 32, "binpack": None}
+# per-format banded chunk width (plan="banded", autotune candidates): the
+# smallest W that clears the ≥4x modeled routing-MAC reduction at default
+# shapes without shrinking the MXU tiles below usefulness (docs/kernels.md
+# §Banded chunked scatter).
+BANDED_CHUNK = {"vbyte": 64, "streamvbyte": 32}
+# per-format default chunk width of the TPU plan. vbyte routes by VPU
+# compaction, which has no chunk axis (docs/kernels.md §Compaction
+# routing); binpack has no length scan, so no chunk axis either.
+DEFAULT_CHUNK = {"vbyte": None, "streamvbyte": BANDED_CHUNK["streamvbyte"],
+                 "binpack": None}
 
 
 def default_plan(epilogue: str = "stream",
@@ -211,7 +217,7 @@ def resolve_plan(plan, *, format: str, epilogue: str,
         return DecodePlan(default_plan(epilogue, format).path, fused=False)
     if plan == "banded":
         return replace(default_plan(epilogue, format),
-                       chunk=_clamp_chunk(DEFAULT_CHUNK.get(format, 64),
+                       chunk=_clamp_chunk(BANDED_CHUNK.get(format),
                                           block_size))
     if plan == "dense":
         return replace(default_plan(epilogue, format), chunk=None)
@@ -606,7 +612,7 @@ def autotune(
                 # no consumer: fused vs unfused is the same program — the
                 # decoder path, block tile and banded chunk width are the
                 # real degrees of freedom
-                w0 = DEFAULT_CHUNK.get(fmt, 64)
+                w0 = BANDED_CHUNK.get(fmt)
                 candidates = [DecodePlan("jnp", True),
                               DecodePlan("jnp", True, chunk=w0)]
                 if fmt == "vbyte":
@@ -619,7 +625,7 @@ def autotune(
                     # footprint is what makes tiles past 8 blocks fit
                     candidates += [DecodePlan("pallas", True, 32, chunk=w0)]
             else:
-                w0 = DEFAULT_CHUNK.get(fmt, 64)
+                w0 = BANDED_CHUNK.get(fmt)
                 candidates = [DecodePlan("jnp", True), DecodePlan("jnp", False),
                               DecodePlan("jnp", True, chunk=w0)]
                 if include_pallas:
@@ -627,7 +633,7 @@ def autotune(
                                    for bt in (8, 16) for w in (None, w0)]
                     candidates += [DecodePlan("pallas", True, 32, chunk=w0),
                                    DecodePlan("pallas", False, 8)]
-            # binpack has no chunk axis (DEFAULT_CHUNK[fmt] is None), which
+            # binpack has no chunk axis (BANDED_CHUNK has no width), which
             # collapses banded candidates onto their dense twins — dedupe
             candidates = list({c.label: c for c in candidates}.values())
             timings = {}

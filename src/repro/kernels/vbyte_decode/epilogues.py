@@ -191,9 +191,10 @@ def _decode_weight_tile(valid, w_payload=None, w_control=None, w_data=None,
     the weight tile's count vector — no extra metadata operands. The
     format discriminator is which operands arrived: ``w_widths`` → binpack,
     ``w_payload`` → vbyte, ``w_control``+``w_data`` → streamvbyte. Always
-    decodes dense (``chunk_width=None``): the weight stride is short
-    (impacts are < 2^impact_bits) and the tile cores are bit-exact for
-    any routing geometry.
+    decodes with the format's unchunked routing (``chunk_width=None``:
+    compaction for vbyte, dense for streamvbyte): the weight stride is
+    short (impacts are < 2^impact_bits) and the tile cores are bit-exact
+    for any routing geometry.
     """
     counts = valid.astype(jnp.int32).sum(axis=1, keepdims=True)
     B = valid.shape[-1]

@@ -7,24 +7,27 @@ paper's 48-byte mask pipeline amortizes pmovmskb latency) is decoded entirely
 branch-free:
 
   * continuation bits via one vectorized compare (pmovmskb analogue),
-  * byte→integer routing via a strict-triangular f32 matmul prefix sum
-    (replaces the 2^12-entry lookup table),
   * within-integer positions via the ≤5-byte closed form
     (replaces the 170 pshufb control masks),
-  * reassembly via a one-hot **MXU** scatter — the systolic array plays the
-    role of pshufb (this is the TPU shuffle engine),
+  * each integer assembled at its terminator byte from the four bytes
+    before it (static lane shifts),
+  * byte→integer routing by **compaction** on the VPU: every terminator
+    moves left by its count of earlier continuation bytes, one round of
+    static lane shifts per radix-16 digit of that count (replaces the
+    2^12-entry lookup table and the pshufb shuffle; docs/kernels.md
+    §Compaction routing),
   * fused differential prefix sum via triangular matmul (the paper's
     pslldq/paddd doubling tree).
 
-32-bit exactness on an f32 MXU is preserved by splitting every 32-bit word
-into 16-bit halves before each matmul: per-output sums stay < 2^24 (f32-exact
-at ``precision=HIGHEST``, which every matmul here passes) and are recombined
-with wrap-around int32 adds (≡ mod 2^32, i.e. uint32).
+The routing is integer arithmetic only, exact mod 2^32 with no matmul. The
+differential epilogue keeps 32-bit exactness on an f32 MXU by splitting every
+32-bit word into 16-bit halves: per-output sums stay < 2^24 (f32-exact at
+``precision=HIGHEST``) and are recombined with wrap-around int32 adds
+(≡ mod 2^32, i.e. uint32).
 
-``chunk_width=W`` swaps the dense O(S²)+O(S·B) routing for the chunked
-banded scatter (``banded.py``): out_idx is monotone with increments ≤ 1,
-so a W-byte chunk's outputs live in one W-slot band — O(S·W) routing MACs,
-bit-identical output (docs/kernels.md §Banded chunked scatter).
+``chunk_width=W`` selects the chunked banded MXU scatter instead
+(``banded.py``): the A/B baseline of the compaction route and an autotune
+candidate, bit-identical output (docs/kernels.md §Banded chunked scatter).
 
 All tensors live in VMEM; block dims are multiples of (8, 128) lanes.
 """
@@ -38,14 +41,99 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from .banded import (banded_scatter_u32, chunked_prefix, exact_dot,
-                     kernel_name, normalize_chunk_width, onehot_scatter,
-                     split_cols, strict_upper)
+                     kernel_name, normalize_chunk_width, split_cols)
 
 
 def _shift_right(x: jax.Array, k: int) -> jax.Array:
     """x[..., i-k] with zero fill — static slices only (Mosaic-safe)."""
     t, s = x.shape
     return jnp.concatenate([jnp.zeros((t, k), x.dtype), x[:, : s - k]], axis=1)
+
+
+def _shift_left(x: jax.Array, k: int) -> jax.Array:
+    """x[..., i+k] with zero fill — static slices only (Mosaic-safe)."""
+    t, s = x.shape
+    return jnp.concatenate([x[:, k:], jnp.zeros((t, k), x.dtype)], axis=1)
+
+
+# Lane shifts on an 8-row tile are bound by latency, not throughput, so the
+# log-step scans below take radix-16 digits: ⌈log₁₆ S⌉ rounds of up to 15
+# independent shifts instead of ⌈log₂ S⌉ dependent ones (docs/kernels.md
+# §Compaction routing).
+_DIGIT_BITS = 4
+_RADIX = 1 << _DIGIT_BITS
+
+
+def _sum_tree(terms: list) -> jax.Array:
+    """Sum of equal-shape int32 arrays as a balanced tree (exact, wraps)."""
+    while len(terms) > 1:
+        terms = ([a + b for a, b in zip(terms[::2], terms[1::2])]
+                 + terms[len(terms) - len(terms) % 2:])
+    return terms[0]
+
+
+def _row_prefix_count(flags: jax.Array) -> jax.Array:
+    """Inclusive row prefix sum of small int32 values, exact in int32.
+
+    Hillis–Steele in radix 16: after the round of step k every lane holds
+    the sum of the 16·k lanes ending at it, as the sum of 16 windows of k.
+    """
+    S = flags.shape[1]
+    k = 1
+    while k < S:
+        flags = _sum_tree([flags] + [_shift_right(flags, a * k)
+                                     for a in range(1, _RADIX) if a * k < S])
+        k *= _RADIX
+    return flags
+
+
+def _terminator_values(contrib: jax.Array, c: tuple) -> jax.Array:
+    """Each integer's value at its terminator byte, exact mod 2³².
+
+    ``contrib[t, i]`` is byte i's payload bits already shifted to its
+    within-integer position; ``c = (c1, c2, c3, c4)`` the continuation
+    flags shifted right by 1…4 lanes (``cj[t, i] = cont[t, i-j]``). An
+    integer has at most 5 bytes, so its value is its terminator's
+    contribution plus those of the up-to-4 bytes before it, each taken
+    while the bytes between continue:
+    ``v_i = x_i + c1_i·(x_{i-1} + c2_i·(x_{i-2} + c3_i·(x_{i-3} + c4_i·x_{i-4})))``
+    with ``x = contrib``. Lanes that are not terminators hold partial sums,
+    which the caller masks.
+    """
+    val = _shift_right(contrib, 4) * c[3]
+    for j in (3, 2, 1):
+        val = (_shift_right(contrib, j) + val) * c[j - 1]
+    return contrib + val
+
+
+def _move_by_digit(x: jax.Array, digit: jax.Array, k: int) -> jax.Array:
+    """Each ``x[t, i]`` moved left by ``digit[t, i]·2^k`` lanes, the moved
+    pieces summed (one round of :func:`_compact_left`)."""
+    parts = [jnp.where(digit == 0, x, 0)]
+    parts += [_shift_left(jnp.where(digit == a, x, 0), a << k)
+              for a in range(1, _RADIX) if a << k < x.shape[1]]
+    return _sum_tree(parts)
+
+
+def _compact_left(vals: jax.Array, shift: jax.Array) -> jax.Array:
+    """Move ``vals[t, i]`` to lane ``i - shift[t, i]`` by static lane shifts,
+    one round per radix-16 digit of the shift, least significant first:
+    the round of the digit at bit k moves each element left by
+    ``digit·2^k`` lanes.
+
+    Contract: dead lanes carry ``vals = shift = 0``; over the live lanes of
+    a row ``shift`` is non-decreasing and the targets ``i - shift`` are
+    strictly increasing and ≥ 0. Then after every round the live elements
+    sit on distinct lanes (docs/kernels.md §Compaction routing), so the
+    moved pieces combine by an int32 add and every live value lands exactly.
+    """
+    n_bits = max(1, (vals.shape[1] - 1).bit_length())
+    for k in range(0, n_bits, _DIGIT_BITS):
+        digit = (shift >> k) & (_RADIX - 1)
+        vals = _move_by_digit(vals, digit, k)
+        if k + _DIGIT_BITS < n_bits:  # the last round's shifts go unread
+            shift = _move_by_digit(shift, digit, k)
+    return vals
 
 
 def _row_cumsum_exact_u32(x: jax.Array, incl_tri: jax.Array) -> jax.Array:
@@ -65,12 +153,15 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     ``[T, B]``. Pure jnp/lax — callable both from a Pallas kernel body and
     from host-level code; every fused epilogue consumes this contract.
 
-    ``chunk_width=None`` runs the dense O(S²)+O(S·B) routing (full
-    triangular prefix matmul + [T, B, S] one-hot scatter). An integer ``W``
-    selects the chunked banded-scatter routing (``banded.py``): out_idx is
-    monotone and increments ≤1 per byte, so chunk ``c``'s bytes land only
-    in slots ``[chunk_base[c], chunk_base[c]+W)`` — O(S·W) MACs, identical
-    uint32 output bit-for-bit.
+    ``chunk_width=None`` runs the compaction routing: integers are
+    assembled at their terminator bytes and compacted left on the VPU in
+    ⌈log₁₆ S⌉ rounds of static lane shifts — integer arithmetic, no
+    matmul. An
+    integer ``W`` selects the chunked banded-scatter routing
+    (``banded.py``): out_idx is monotone and increments ≤1 per byte, so
+    chunk ``c``'s bytes land only in slots ``[chunk_base[c],
+    chunk_base[c]+W)`` — O(S·W) MXU MACs, identical uint32 output
+    bit-for-bit.
     """
     T, S = payload.shape
     B = block_size
@@ -91,16 +182,20 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     contrib = (b & 0x7F) << (7 * pos)  # int32, wraps ≡ uint32
 
     if chunk_width is None:
-        # dense routing: exclusive prefix sum over the full byte axis
-        # (out_idx[t,i] = #terminators < i) + full-width one-hot scatter
-        out_idx = exact_dot(end, strict_upper(S))
-        keep = out_idx < counts  # [T,S] < [T,1]
-        contrib = jnp.where(keep, contrib, 0)
-        # one-hot MXU scatter: out[t,j] = Σ_i [out_idx[t,i]==j]·contrib[t,i]
-        # (masked bytes carry zero, so where they route is irrelevant)
-        lo = onehot_scatter(out_idx, contrib & 0xFFFF, B)
-        hi = onehot_scatter(out_idx, (contrib >> 16) & 0xFFFF, B)
-        out = lo.astype(jnp.int32) + (hi.astype(jnp.int32) << 16)
+        # compaction routing: the integer ending at terminator i goes to
+        # slot #terminators before i, i.e. i moves left by its count of
+        # earlier continuation bytes; other lanes are dead (value, shift 0).
+        # Terminators past the block's count (padding zeros) need no mask:
+        # they land on slots ≥ count, which the valid mask below zeroes.
+        term = end == 1
+        val = jnp.where(term, _terminator_values(contrib, (c1, c2, c3, c4)),
+                        0)
+        shift = jnp.where(term, _row_prefix_count(cont) - cont, 0)
+        out = _compact_left(val, shift)
+        if S < B:
+            out = jnp.concatenate(
+                [out, jnp.zeros((T, B - S), out.dtype)], axis=1)
+        out = out[:, :B]
     else:
         W = normalize_chunk_width(chunk_width, B)
         # chunked prefix: loc = #terminators earlier in the chunk (the

@@ -240,15 +240,18 @@ def test_decode_refused_in_prepare_records_no_launch(rng,
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("fmt,chunk,epilogue,want", [
     ("vbyte", 64, None, "vbyte_decode_banded_w64"),
-    ("vbyte", None, None, "vbyte_decode_dense"),
+    ("vbyte", None, None, "vbyte_decode_compact"),
     ("streamvbyte", 32, None, "streamvbyte_decode_banded_w32"),
     ("binpack", None, None, "binpack_decode_gather"),
     ("vbyte", 64, "bag_sum", "vbyte_fused_bag_sum_banded_w64"),
     ("binpack", None, "membership", "binpack_fused_membership_gather"),
+    ("vbyte", None, "membership", "vbyte_fused_membership_compact"),
+    ("streamvbyte", None, None, "streamvbyte_decode_dense"),
 ])
 def test_pallas_call_carries_kernel_name(rng, fmt, chunk, epilogue, want):
     """Each decode ``pallas_call`` is named for its format, its fused
-    epilogue and its routing core; binpack ignores the chunk width."""
+    epilogue and its routing core; binpack ignores the chunk width, and
+    the unchunked core is vbyte's compaction and streamvbyte's dense one."""
     vals = np.sort(rng.integers(0, 512, 64)).astype(np.uint64)
     arr = CompressedIntArray.encode(vals, format=fmt, block_size=128,
                                     differential=True)

@@ -29,6 +29,7 @@ from repro.kernels.vbyte_decode import dispatch, epilogues, ops
 N_BLOCKS = 1024
 B = 128  # integers per block
 STRIDE = 640  # vbyte payload bytes per block (5 bytes × 128)
+CELL_STRIDE = 256  # vbyte stride of the ClueWeb09-B decode cells' lists
 DATA_STRIDE = 512  # streamvbyte / binpack data bytes per block
 W_STRIDE = 256  # impact-stream payload bytes per block (impacts < 2^8)
 
@@ -71,11 +72,11 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _format_operands(sharding, fmt, prefix=""):
+def _format_operands(sharding, fmt, prefix="", stride=STRIDE):
     """Shapes of ``CompressedIntArray.device_operands()`` at real tiles."""
     u8 = jnp.uint8
     if fmt == "vbyte":
-        ops_ = {"payload": _spec(sharding, (N_BLOCKS, STRIDE), u8)}
+        ops_ = {"payload": _spec(sharding, (N_BLOCKS, stride), u8)}
     elif fmt == "streamvbyte":
         ops_ = {"control": _spec(sharding, (N_BLOCKS, B // 4), u8),
                 "data": _spec(sharding, (N_BLOCKS, DATA_STRIDE), u8)}
@@ -111,11 +112,23 @@ def test_default_decode_plan_compiles(one_chip, tpu_plan, fmt):
     _assert_mosaic_kernel(lowered)
 
 
+def test_default_vbyte_plan_compiles_at_cell_stride(one_chip, tpu_plan):
+    plan = tpu_plan("vbyte")
+    assert plan.path == "pallas" and plan.chunk is None, plan
+    operands = {**_format_operands(one_chip, "vbyte", stride=CELL_STRIDE),
+                **_meta(one_chip)}
+    lowered = ops.vbyte_decode_blocked.lower(
+        **operands, block_size=B, differential=True,
+        block_tile=plan.block_tile, chunk_width=plan.chunk, interpret=False)
+    _assert_mosaic_kernel(lowered)
+
+
 @pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("vbyte", 32),
-                                       ("streamvbyte", None)])
+                                       ("streamvbyte", None), ("vbyte", 64)])
 def test_other_routing_widths_compile(one_chip, fmt, chunk):
-    # the dense cores decode every impact stream in the weighted
-    # epilogues; the other widths are autotune candidates
+    # the unchunked cores (vbyte compaction, streamvbyte dense) decode
+    # every impact stream in the weighted epilogues; the banded widths are
+    # vbyte's A/B baseline (plan="banded") and autotune candidates
     operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
     lowered = DECODE_FNS[fmt].lower(
         **operands, block_size=B, differential=True, block_tile=8,
