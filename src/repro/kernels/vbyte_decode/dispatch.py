@@ -72,10 +72,11 @@ DEFAULT_CACHE_PATH = (
     if os.path.basename(_SRC_DIR) == "src"
     else "experiments/autotune.json")
 
-# broadcast epilogue operands (embedding tables) above this size cannot be
-# VMEM-resident per grid step on TPU; the fused Pallas plan falls back to
-# pallas-decode + jnp epilogue (a vocab-tiled grid dimension with masked
-# partial sums is the real fix — see docs/kernels.md §TPU notes)
+# broadcast epilogue operands (bag_sum's embedding table, probe sets) above
+# this size cannot be VMEM-resident per grid step on TPU; the fused Pallas
+# plan falls back to pallas-decode + jnp epilogue (docs/kernels.md §TPU
+# notes). dot_score's table is not broadcast: its kernel fetches rows from
+# HBM, so it runs fused at every table size.
 VMEM_BROADCAST_BUDGET = 4 << 20
 
 
@@ -308,12 +309,14 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
                             interpret=interpret)
 
     if plan.path == "pallas" and plan.fused:
-        # broadcast extras (tables) must be VMEM-resident per grid step;
-        # past the budget, degrade to pallas-decode + jnp epilogue instead
-        # of failing Mosaic compilation (docs/kernels.md §TPU notes)
+        # broadcast extras must be VMEM-resident per grid step; past the
+        # budget, degrade to pallas-decode + jnp epilogue instead of failing
+        # Mosaic compilation (docs/kernels.md §TPU notes). Extras the kernel
+        # reads from HBM (dot_score's table) are not broadcast.
         broadcast_bytes = sum(
             int(np.prod(v.shape)) * v.dtype.itemsize
-            for k, v in extras.items() if k not in ep.tiled_extras)
+            for k, v in extras.items()
+            if k not in ep.tiled_extras and k not in ep.hbm_extras)
         if broadcast_bytes <= VMEM_BROADCAST_BUDGET:
             return eplib.fused_decode(
                 operands, extras, format=format, epilogue=epilogue,
@@ -390,7 +393,7 @@ def _build_sharded_fn(mesh, axes: tuple, format: str, epilogue: str,
                  for k in extra_keys}
     if epilogue == "dot_score":
         out_specs = (spec_block,
-                     P(axes, None, None) if multi_query else spec_block)
+                     P(None, axes, None) if multi_query else spec_block)
     elif epilogue == "checksum":
         # (decoded grid, per-block checksum column) — both block-leading
         out_specs = (spec_block, spec_block)
@@ -428,8 +431,10 @@ def decode(
 
     Returns the epilogue's output: the ``uint32 [n_blocks, block_size]``
     grid for ``epilogue="stream"``, ``[n_blocks, d]`` bag sums for
-    ``"bag_sum"``, ``(ids, scores)`` for ``"dot_score"``, rebased edge ids
-    for ``"adjacency_rebase"``.
+    ``"bag_sum"``, ``(ids, scores)`` for ``"dot_score"`` (float32 scores
+    ``[n_blocks, block_size]`` for one query, ``[n_queries, n_blocks,
+    block_size]`` for a query matrix), rebased edge ids for
+    ``"adjacency_rebase"``.
 
     When the operands' block dimension is sharded over a >1-device mesh
     axis (``CompressedIntArray.shard``), the plan runs per shard under

@@ -29,7 +29,9 @@ Registered epilogues:
 * ``dot_score``        — retrieval scoring: decoded candidate ids gather
                          item vectors and dot against a query; returns
                          ``(ids, scores)`` so the [C, d] candidate-vector
-                         matrix never exists in HBM.
+                         matrix never exists in HBM. Its Pallas form keeps
+                         the table in HBM and fetches rows by DMA
+                         (``gather_kernel.py``), at every table size.
 * ``adjacency_rebase`` — GNN adjacency: per-edge ``incl - row_gap_base``
                          subtraction fused into the differential epilogue.
 * ``membership``       — inverted-index intersection: decode a postings
@@ -83,6 +85,7 @@ from jax.experimental import pallas as pl
 
 from .banded import kernel_name
 from .binpack_kernel import binpack_decode_tile
+from .gather_kernel import dot_score_pallas, gather_rows, row_table, score_rows
 from .kernel import decode_tile, prefix_sum_tile
 from .stream_kernel import stream_decode_tile
 
@@ -120,15 +123,17 @@ def _bag_sum_apply(vals, valid, *, table):
 
 
 def _dot_score_apply(vals, valid, *, table, query):
+    # table: [V, d] float, or its gather_kernel.row_table (what the Pallas
+    # kernel fetches rows from); both give the same vectors
     T, B = vals.shape
     ids = jnp.where(valid, vals, 0)  # pad slots score id 0 (the pad row)
-    vecs = jnp.take(table, ids.reshape(-1), axis=0, mode="clip")
-    vecs = vecs.reshape(T, B, -1)
     q = query.reshape(-1, query.shape[-1])  # [n_queries, d]
+    s = score_rows(gather_rows(table, ids.reshape(-1), q.shape[1]), q)
     if q.shape[0] == 1:  # single query: scores [T, B] (the original contract)
-        return ids, jnp.einsum("tbd,d->tb", vecs, q[0]).astype(jnp.float32)
-    # microbatched queries (the serving engine's bucket): scores [T, B, q]
-    return ids, jnp.einsum("tbd,qd->tbq", vecs, q).astype(jnp.float32)
+        return ids, s.reshape(T, B)
+    # microbatched queries (the serving engine's bucket): scores [q, T, B],
+    # each query's scores contiguous, as the kernel writes them
+    return ids, s.reshape(-1, T, B)
 
 
 def _checksum_apply(vals, valid):
@@ -257,6 +262,7 @@ class Epilogue:
     extras: tuple[str, ...] = ()
     optional_extras: tuple[str, ...] = ()  # e.g. format-tagged weight operands
     tiled_extras: tuple[str, ...] = ()  # extras sliced per tile like the grid
+    hbm_extras: tuple[str, ...] = ()  # extras the kernel reads from HBM by DMA
     requires_differential: bool | None = None  # None = either
     # (n_blocks, block_size, block_tile, extras dict) -> (out_shape, out_spec)
     # — single structs or tuples of structs for multi-output epilogues
@@ -296,17 +302,6 @@ def _bag_sum_out(nb, B, bt, extras):
             pl.BlockSpec((bt, d), lambda g: (g, 0)))
 
 
-def _dot_score_out(nb, B, bt, extras):
-    ids, ids_spec = _grid_out(nb, B, bt, jnp.int32)
-    nq = extras["query"].size // extras["query"].shape[-1]
-    if nq == 1:
-        scores, scores_spec = _grid_out(nb, B, bt, jnp.float32)
-    else:
-        scores = jax.ShapeDtypeStruct((nb, B, nq), jnp.float32)
-        scores_spec = pl.BlockSpec((bt, B, nq), lambda g: (g, 0, 0))
-    return (ids, scores), (ids_spec, scores_spec)
-
-
 def _checksum_out(nb, B, bt, extras):
     return ((jax.ShapeDtypeStruct((nb, B), jnp.int32),
              jax.ShapeDtypeStruct((nb, 1), jnp.int32)),
@@ -329,8 +324,9 @@ EPILOGUES = {
     "stream": Epilogue("stream", _stream_apply, out_info=_stream_out),
     "bag_sum": Epilogue("bag_sum", _bag_sum_apply, extras=("table",),
                         out_info=_bag_sum_out),
+    # its Pallas form is gather_kernel.dot_score_pallas (see fused_decode)
     "dot_score": Epilogue("dot_score", _dot_score_apply,
-                          extras=("table", "query"), out_info=_dot_score_out),
+                          extras=("table", "query"), hbm_extras=("table",)),
     "checksum": Epilogue("checksum", _checksum_apply, out_info=_checksum_out),
     "adjacency_rebase": Epilogue(
         "adjacency_rebase", _adjacency_rebase_apply, extras=("edge_base",),
@@ -390,6 +386,30 @@ def apply_grid(epilogue: str, grid_u32: jax.Array, counts: jax.Array,
 # ---------------------------------------------------------------------------
 # fused Pallas path: decode-tile core + epilogue in one kernel
 # ---------------------------------------------------------------------------
+def _tile_decoder(format: str, *, block_size: int, differential: bool,
+                  chunk_width: int | None):
+    """``decode(*fmt_tiles, counts, bases) -> (vals, valid)``: the format's
+    decode-tile core on one VMEM tile, then the differential prefix sum."""
+    def decode(*tiles):
+        *fmt_tiles, counts, bases = tiles
+        if format == "vbyte":
+            vals, valid = decode_tile(*fmt_tiles, counts,
+                                      block_size=block_size,
+                                      chunk_width=chunk_width)
+        elif format == "binpack":
+            vals, valid = binpack_decode_tile(*fmt_tiles, counts,
+                                              block_size=block_size,
+                                              chunk_width=chunk_width)
+        else:
+            vals, valid = stream_decode_tile(*fmt_tiles, counts,
+                                             block_size=block_size,
+                                             chunk_width=chunk_width)
+        if differential:
+            vals = prefix_sum_tile(vals, valid, bases)
+        return vals, valid
+    return decode
+
+
 def fused_decode_pallas(
     format: str,
     fmt_arrays: tuple,  # ("payload",) or ("control", "data") uint8 arrays
@@ -404,8 +424,11 @@ def fused_decode_pallas(
     chunk_width: int | None = None,
     interpret: bool = False,
 ):
-    """Raw pallas_call builder: one pass over (decode tile → epilogue)."""
+    """Raw pallas_call builder: one pass over (decode tile → epilogue).
+    ``dot_score`` has a kernel of its own (:func:`fused_decode`)."""
     ep = get_epilogue(epilogue)
+    if ep.out_info is None:
+        raise ValueError(f"epilogue {epilogue!r} has no generic fused kernel")
     nb = fmt_arrays[0].shape[0]
     if nb % block_tile:
         raise ValueError(f"n_blocks={nb} must be a multiple of "
@@ -424,28 +447,14 @@ def fused_decode_pallas(
     ]
     out_shape, out_specs = ep.out_info(nb, block_size, block_tile, extras)
     multi = isinstance(out_shape, tuple)
+    decode = _tile_decoder(format, block_size=block_size,
+                           differential=differential, chunk_width=chunk_width)
 
     def kernel(*refs):
-        counts_ref, bases_ref = refs[n_fmt], refs[n_fmt + 1]
         extra_vals = {k: refs[n_fmt + 2 + i][...]
                       for i, k in enumerate(extra_names)}
         out_refs = refs[n_fmt + 2 + len(extra_names):]
-        if format == "vbyte":
-            vals, valid = decode_tile(refs[0][...], counts_ref[...],
-                                      block_size=block_size,
-                                      chunk_width=chunk_width)
-        elif format == "binpack":
-            vals, valid = binpack_decode_tile(refs[0][...], refs[1][...],
-                                              counts_ref[...],
-                                              block_size=block_size,
-                                              chunk_width=chunk_width)
-        else:
-            vals, valid = stream_decode_tile(refs[0][...], refs[1][...],
-                                             counts_ref[...],
-                                             block_size=block_size,
-                                             chunk_width=chunk_width)
-        if differential:
-            vals = prefix_sum_tile(vals, valid, bases_ref[...])
+        vals, valid = decode(*(r[...] for r in refs[:n_fmt + 2]))
         res = ep.apply(vals, valid, **extra_vals)
         for r, oref in zip(res if multi else (res,), out_refs):
             oref[...] = r
@@ -510,6 +519,20 @@ def fused_decode(
 
     counts2 = counts.astype(jnp.int32)[:, None]
     bases2 = lax.bitcast_convert_type(bases.astype(jnp.uint32), jnp.int32)[:, None]
+    if epilogue == "dot_score":
+        # the table stays in HBM; rows are fetched by DMA (gather_kernel.py)
+        table, query = extras["table"], extras["query"]
+        q = query.reshape(-1, query.shape[-1])
+        ids, scores = dot_score_pallas(
+            _tile_decoder(format, block_size=block_size,
+                          differential=differential, chunk_width=chunk_width),
+            fmt_arrays, counts2, bases2,
+            table if table.dtype == jnp.uint32 else row_table(table), q,
+            block_size=block_size, block_tile=block_tile,
+            name=kernel_name(format, chunk_width, epilogue),
+            interpret=interpret)
+        scores = scores.reshape(q.shape[0], -1, block_size)[:, :nb]
+        return ids[:nb], scores[0] if q.shape[0] == 1 else scores
     out = fused_decode_pallas(
         format, fmt_arrays, counts2, bases2, extras,
         epilogue=epilogue, block_size=block_size, differential=differential,

@@ -106,63 +106,133 @@ def serve_recsys(cfg, batch: int):
 # ---------------------------------------------------------------------------
 # the batched compressed serving engine
 # ---------------------------------------------------------------------------
+ITEM_TABLE_CHUNK_ROWS = 1 << 18  # item-tower rows per step of the table build
+
+
+def build_item_table(params, cfg, *, dtype, chunk_rows: int = ITEM_TABLE_CHUNK_ROWS):
+    """The item tower over every item row, as a ``dot_score`` row table
+    (``gather_kernel.row_table``: ``uint32 [padded_rows(vocab_rows), w]``).
+
+    Built ``chunk_rows`` rows at a time into one buffer that each step
+    updates in place, so the build holds the table and one chunk's
+    activations, never the whole vocabulary's: at 2^23 items the first
+    tower layer alone would be 17 GB. The last chunk is shifted back to end
+    at the last row, so every chunk has one shape and one program; the
+    padding rows then repeat the last row, as ``row_table``'s do.
+    """
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.vbyte_decode.gather_kernel import pack_rows, padded_rows
+    from repro.models import recsys
+
+    V = cfg.vocab_rows
+    chunk = min(chunk_rows, V)
+
+    def rows(p, start):
+        ids = start + jnp.arange(chunk, dtype=jnp.int32)
+        return pack_rows(recsys.item_tower(p, ids, cfg, dtype=dtype)
+                         .astype(dtype))
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def fill(table, p, start):
+        return jax.lax.dynamic_update_slice(table, rows(p, start), (start, 0))
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def pad(table):
+        return table.at[V:].set(table[V - 1])
+
+    shape = jax.eval_shape(rows, params, 0)
+    table = jnp.zeros((padded_rows(V), shape.shape[1]), shape.dtype)
+    for start in range(0, V, chunk):
+        table = fill(table, params, min(start, V - chunk))
+    return pad(table)
+
+
+def _mask_and_topk(ids, scores, *, top_k: int):
+    import jax
+    import jax.numpy as jnp
+
+    flat_ids = ids.reshape(-1)  # [C]
+    if scores.ndim == 2:  # single query: [nb, B]
+        s = scores.reshape(1, -1)
+    else:  # [b, nb, B] -> [b, C]
+        s = scores.reshape(scores.shape[0], -1)
+    s = jnp.where(flat_ids[None, :] == 0, -jnp.inf, s)  # mask pad slots
+    top_s, top_i = jax.lax.top_k(s, top_k)
+    return top_s, jnp.take(flat_ids, top_i)
+
+
 class ServingEngine:
-    """Serve retrieval / embedding-bag requests from a sharded compressed corpus.
+    """Serve retrieval / embedding-bag requests from compressed corpora.
 
     Architecture (docs/serving.md):
 
-    * **Resident corpus** — the candidate id list lives compressed on the
-      mesh: ``CompressedIntArray.shard(mesh, axis="data")`` places the block
+    * **Resident corpora** — one or several candidate id lists (one per
+      market, say) live compressed on the device, or on the mesh:
+      ``CompressedIntArray.shard(mesh, axis="data")`` places the block
       dimension across devices, and every decode runs block-parallel under
       ``shard_map`` where the bytes sit (no re-upload per request, no
       cross-device decode traffic).
-    * **Precomputed item table** — the two-tower item tower runs ONCE over
-      the vocabulary at engine build; serving gathers from the resulting
-      ``[V, d]`` table inside the fused ``dot_score`` decode epilogue, so a
-      request costs user-tower + decode-gather-dot + top-k.
+    * **Serving tables in the compute dtype** — every floating parameter is
+      held in ``dtype`` (bf16 by default), as inference stores its tables;
+      the towers compute in that dtype either way.
+    * **Precomputed item table** — the two-tower item tower runs once over
+      the vocabulary at engine build, in chunks of rows
+      (:func:`build_item_table`), into a ``dot_score`` row table. Serving
+      fetches rows of it from HBM inside the fused ``dot_score`` decode
+      kernel, so a request costs user-tower + decode-gather-dot + top-k.
     * **Bucketed microbatching** — requests are grouped to the next bucket
       size (default 1/2/4/8) and padded, so every serving step hits one of a
       fixed set of jitted shapes — no retracing in steady state. The decoded
       corpus is shared by the whole microbatch: the ``dot_score`` epilogue
       takes the bucket's ``[b, d]`` query matrix in one pass.
 
-    ``retrieve(user_ids, hists)`` serves one microbatch; ``run_workload``
-    drives a request list through the bucketing loop and reports aggregate
-    QPS and per-request p50/p99 latency.
+    ``retrieve(user_ids, hists, corpus=j)`` serves one microbatch against
+    corpus ``j``; ``run_workload`` drives a request list through the
+    bucketing loop and reports aggregate QPS and per-request p50/p99
+    latency.
     """
 
     def __init__(self, params, cfg, corpus, *, mesh=None, axis="data",
                  top_k: int = 10, buckets=(1, 2, 4, 8),
                  plan="auto", dtype=None):
+        import functools
+
         import numpy as np
 
         import jax
         import jax.numpy as jnp
 
+        from repro.core import CompressedIntArray
         from repro.models import recsys
         from repro.nn import layers as nnl
 
         self._np, self._jax, self._jnp = np, jax, jnp
         self.cfg = cfg
-        self.params = params
         self.top_k = top_k
         self.plan = plan
         self.buckets = tuple(sorted(buckets))
-        self.dtype = dtype or nnl.DEFAULT_COMPUTE_DTYPE
+        self.dtype = dtype = dtype or nnl.DEFAULT_COMPUTE_DTYPE
         self.mesh = mesh
+        self.params = jax.tree.map(
+            lambda x: (x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
+                       else x), params)
 
-        # resident corpus: sharded over the mesh axis, or (single device)
+        # resident corpora: sharded over the mesh axis, or (single device)
         # placed once — either way requests never re-upload the bytes
-        self.corpus = (corpus.shard(mesh, axis=axis) if mesh is not None
-                       else corpus.replace_leaves(**corpus.device_operands()))
+        corpora = ([corpus] if isinstance(corpus, CompressedIntArray)
+                   else list(corpus))
+        self.corpora = [c.shard(mesh, axis=axis) if mesh is not None
+                        else c.replace_leaves(**c.device_operands())
+                        for c in corpora]
 
-        # precompute the item-vector table once: item_tower over the whole
-        # (rounded) vocabulary. Row 0 is the pad row; dot_score pad slots
-        # gather it, and retrieve() masks id==0 before top-k.
-        item_ids = jnp.arange(cfg.vocab_rows, dtype=jnp.int32)
-        table = jax.jit(
-            lambda p: recsys.item_tower(p, item_ids, cfg, dtype=self.dtype)
-        )(params).astype(self.dtype)
+        # the item-vector table, built once. Row 0 is the pad row;
+        # dot_score pad slots gather it, and retrieve() masks id==0 before
+        # top-k.
+        table = build_item_table(self.params, cfg, dtype=dtype)
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -173,8 +243,9 @@ class ServingEngine:
         # itself per (mesh, workload) inside the dispatch layer
         self._user_fn = jax.jit(
             lambda p, uid, hist: recsys.user_tower(p, uid, hist, cfg,
-                                                   dtype=self.dtype))
-        self._topk_fn = jax.jit(self._mask_and_topk)
+                                                   dtype=dtype))
+        self._topk_fn = jax.jit(functools.partial(_mask_and_topk,
+                                                  top_k=top_k))
         self._stats = []
         # liveness: one heartbeat per served microbatch; run_workload
         # reports the detector's straggler classification (empty when
@@ -184,35 +255,53 @@ class ServingEngine:
         self.detector = StragglerDetector()
         self._step = 0
 
-    # -- retrieval ---------------------------------------------------------
-    def _mask_and_topk(self, ids, scores):
-        jnp = self._jnp
-        flat_ids = ids.reshape(-1)  # [C]
-        if scores.ndim == 2:  # single query: [nb, B]
-            s = scores.reshape(1, -1)
-        else:  # [nb, B, b] -> [b, C]
-            s = scores.reshape(-1, scores.shape[-1]).T
-        s = jnp.where(flat_ids[None, :] == 0, -jnp.inf, s)  # mask pad slots
-        top_s, top_i = self._jax.lax.top_k(s, self.top_k)
-        return top_s, jnp.take(flat_ids, top_i)
+    @property
+    def corpus(self):
+        """The first resident corpus (the only one of a one-corpus engine)."""
+        return self.corpora[0]
 
+    # -- retrieval ---------------------------------------------------------
     def bucket_of(self, k: int) -> int:
         for b in self.buckets:
             if b >= k:
                 return b
         return self.buckets[-1]
 
-    def retrieve(self, user_ids, hists):
-        """Serve one microbatch: [b] user ids + [b, L] histories →
-        (scores [b, k], item ids [b, k]). b must be one of the buckets."""
+    def user_vectors(self, user_ids, hists):
+        """The ``[b, d]`` query matrix ``retrieve`` scores with: the user
+        tower over [b] user ids and [b, L] histories, in the engine's
+        dtype."""
+        return self._user_fn(self.params, user_ids, hists)
+
+    def retrieve(self, user_ids, hists, *, corpus: int = 0):
+        """Serve one microbatch against resident corpus ``corpus``: [b]
+        user ids + [b, L] histories → (scores [b, k], item ids [b, k]).
+        b must be one of the buckets.
+
+        Traced as one ``retrieve`` span with children
+        ``retrieve.user_tower``, ``retrieve.score`` (the fused
+        ``dispatch.decode`` call) and ``retrieve.topk``; each returns before
+        the device has finished. Counts ``retrieval_candidates_total`` (the
+        corpus's ids, each scored for the whole microbatch) and
+        ``dot_score_rows_fetched_total`` (table rows the scoring fetches,
+        padding slots included)."""
         from repro.kernels.vbyte_decode import dispatch
 
-        u = self._user_fn(self.params, user_ids, hists)  # [b, d]
-        ids, scores = dispatch.decode(
-            self.corpus, epilogue="dot_score",
-            epilogue_operands={"table": self.item_table, "query": u},
-            plan=self.plan)
-        return self._topk_fn(ids, scores)
+        arr = self.corpora[corpus]
+        with _obs_trace("retrieve", corpus=int(corpus),
+                        bucket=int(user_ids.shape[0])):
+            with _obs_trace("retrieve.user_tower"):
+                u = self.user_vectors(user_ids, hists)  # [b, d]
+            with _obs_trace("retrieve.score"):
+                ids, scores = dispatch.decode(
+                    arr, epilogue="dot_score",
+                    epilogue_operands={"table": self.item_table, "query": u},
+                    plan=self.plan)
+            with _obs_trace("retrieve.topk"):
+                out = self._topk_fn(ids, scores)
+        _obs_counter_inc("retrieval_candidates_total", arr.n)
+        _obs_counter_inc("dot_score_rows_fetched_total", ids.size)
+        return out
 
     # -- embedding-bag endpoint -------------------------------------------
     def embed_bags(self, bags, *, format="vbyte"):
@@ -239,16 +328,23 @@ class ServingEngine:
 
     # -- workload driver ---------------------------------------------------
     def warmup(self):
-        """Compile every bucket shape up front (excluded from latencies)."""
+        """Compile every bucket shape against every corpus shape up front
+        (excluded from latencies)."""
         np, jnp = self._np, self._jnp
         rng = np.random.default_rng(0)
+        shapes = {}
+        for j, c in enumerate(self.corpora):
+            shapes.setdefault(tuple(v.shape for v in c.device_operands()
+                                    .values()), j)
         for b in self.buckets:
             uid = jnp.asarray(rng.integers(1, max(self.cfg.n_users, 2), b),
                               jnp.int32)
             hist = jnp.asarray(
                 rng.integers(1, self.cfg.n_items, (b, self.cfg.seq_len)),
                 jnp.int32)
-            self._jax.block_until_ready(self.retrieve(uid, hist))
+            for j in shapes.values():
+                self._jax.block_until_ready(self.retrieve(uid, hist,
+                                                          corpus=j))
 
     def run_workload(self, requests, *, max_batch: int | None = None) -> dict:
         """Drive (user_id, hist) requests through the microbatching loop.

@@ -15,6 +15,7 @@ resolution only, so a change of the TPU default plan is compiled here
 automatically.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -195,3 +196,37 @@ def test_sharded_search_decode_compiles(topo, tpu_plan, epilogue):
     text = fn.lower(operands, extras).compile().as_text()
     assert "tpu_custom_call" in text
     assert not [c for c in COLLECTIVES if c in text]
+
+
+RETRIEVAL_ROWS = 8389120  # two-tower item table rows (2^23 items + pad)
+RETRIEVAL_BLOCKS = 8192  # 2^20 candidates in 128-int blocks
+
+
+@pytest.mark.parametrize("table_dtype,nq", [(jnp.bfloat16, 8),
+                                            (jnp.float32, 1)])
+def test_dot_score_hbm_gather_compiles(one_chip, tpu_plan, table_dtype, nq):
+    # the fused dot_score at the served shape: a 2^23-row table left in HBM
+    # as a row table, 2^20 candidates per call; Mosaic must accept the
+    # span and group DMAs addressed by decoded ids and the rows picked from
+    # them at dynamic offsets, and XLA must pass the table to the
+    # kernel as it is, with no copy or relayout of it
+    d = 256
+    plan = tpu_plan("vbyte", "dot_score")
+    assert plan.path == "pallas" and plan.fused, plan
+    w = d // 2 if table_dtype == jnp.bfloat16 else d
+    table = _spec(one_chip, (RETRIEVAL_ROWS, w), jnp.uint32)
+    operands = {"payload": _spec(one_chip, (RETRIEVAL_BLOCKS, CELL_STRIDE),
+                                 jnp.uint8),
+                "counts": _spec(one_chip, (RETRIEVAL_BLOCKS,), jnp.int32),
+                "bases": _spec(one_chip, (RETRIEVAL_BLOCKS,), jnp.uint32)}
+    extras = {"table": table,
+              "query": _spec(one_chip, (nq, d), table_dtype)}
+    lowered = epilogues.fused_decode.lower(
+        operands, extras, format="vbyte", epilogue="dot_score", block_size=B,
+        differential=True, block_tile=plan.block_tile,
+        chunk_width=plan.chunk, interpret=False)
+    text = lowered.compile().as_text()
+    assert "vbyte_fused_dot_score_compact" in text
+    # no instruction of the program makes a table-sized value
+    made = re.compile(rf"= u32\[{RETRIEVAL_ROWS},{w}\]\S* (?!parameter)")
+    assert [ln for ln in text.splitlines() if made.search(ln)] == []
