@@ -35,13 +35,45 @@ each block's bag/score/rebase output is block-local). ``plan="sharded"``
 forces this path (raises if the operands aren't sharded); otherwise it is
 auto-selected. Detection needs concrete arrays — call :func:`decode`
 outside any enclosing ``jit`` (it jits internally) to use it.
+
+**Per-signature launch cache.** Everything :func:`decode` resolves before
+it launches (operand checks, epilogue checks, plan resolution, metadata
+shape checks, mesh detection) is a pure function of the call's signature,
+so the first call of a signature stores one entry and every later call
+runs only that entry: one key, one lookup, one program call.
+
+* *Key:* the static aux (format, block size, differential), each format
+  leaf's and each extra's name, shape, dtype and sharding, the epilogue,
+  the ``plan`` argument, ``interpret``, the autotune cache's path
+  (``REPRO_AUTOTUNE_CACHE``) and a generation number. Leaves are read off
+  a ``CompressedIntArray`` by ``getattr``, with no ``jnp.asarray``.
+* *Entry:* no arrays, only what the signature fixed: the span attributes
+  and one callable over the leaves and extras with everything static bound
+  in. A single-program plan is one ``jit`` of the whole call, with the
+  ``[n_blocks, 1]`` → ``[n_blocks]`` metadata normalisation inside it; an
+  unfused plan keeps its two programs; block-sharded operands run
+  ``_build_sharded_fn``'s program.
+* *Bypass:* a call whose leaves or extras are not all concrete
+  ``jax.Array``s (numpy input, tracers under an enclosing ``jit``) takes
+  the full path and stores nothing, as does a call that raises: a
+  malformed call raises on every call.
+* *Invalidation:* reloading the autotune cache (``load_cache(reload=True)``,
+  which :func:`autotune` ends with, or a switch of its path) bumps the
+  generation and drops every entry.
+* *Bound:* an LRU of :data:`LAUNCH_CACHE_SIZE` entries.
+
+``decode_fastpath_total{result=hit|miss|bypass}`` counts which path each
+call took (only while telemetry is installed).
 """
 from __future__ import annotations
 
 import functools
 import json
 import os
+import threading
 import time
+from collections import OrderedDict
+from collections.abc import Callable
 from dataclasses import asdict, dataclass, replace
 
 import numpy as np
@@ -53,6 +85,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.obs import counter_inc as _obs_counter_inc, trace as _obs_trace
 
+from repro.core.compressed_array import FORMAT_LEAVES, CompressedIntArray
 from repro.core.vbyte import binpack_masked as bpk_masked
 from repro.core.vbyte import masked as vmasked
 from repro.core.vbyte import stream_masked as svb_masked
@@ -150,6 +183,8 @@ def load_cache(path: str | None = None, *, reload: bool = False) -> dict:
     global _CACHE, _CACHE_FILE
     path = path or cache_path()
     if _CACHE is None or _CACHE_FILE != path or reload:
+        if _CACHE is not None:
+            _invalidate_launches()  # their plans came from the old cache
         _CACHE_FILE = path
         try:
             with open(path) as f:
@@ -293,6 +328,27 @@ def _apply_only(grid: jax.Array, counts: jax.Array, extras: dict, *,
     return eplib.apply_grid(epilogue, grid, counts, extras)
 
 
+def _run_plan(extras: dict, *, epilogue: str,
+              plan: DecodePlan) -> tuple[DecodePlan, bool]:
+    """The plan :func:`_execute` runs for these extras, and whether it is a
+    downgrade: broadcast extras must be VMEM-resident per grid step, so past
+    the budget a fused Pallas plan degrades to pallas-decode + jnp epilogue
+    instead of failing Mosaic compilation (docs/kernels.md §TPU notes).
+    Extras the kernel reads from HBM (dot_score's table) are not broadcast.
+    Reads only the extras' shapes and dtypes."""
+    if not (plan.path == "pallas" and plan.fused) or epilogue == "stream":
+        return plan, False
+    ep = eplib.get_epilogue(epilogue)
+    broadcast_bytes = sum(
+        int(np.prod(v.shape)) * v.dtype.itemsize
+        for k, v in extras.items()
+        if k not in ep.tiled_extras and k not in ep.hbm_extras)
+    if broadcast_bytes <= VMEM_BROADCAST_BUDGET:
+        return plan, False
+    return DecodePlan("pallas", fused=False, block_tile=plan.block_tile,
+                      chunk=plan.chunk), True
+
+
 def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
              block_size: int, differential: bool, plan: DecodePlan,
              interpret: bool | None = None):
@@ -302,31 +358,21 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
     this function per shard under ``shard_map``, which is what makes the
     sharded decode bit-exact with the single-device one by construction.
     """
-    ep = eplib.get_epilogue(epilogue)
     if epilogue == "stream":
         return _decode_grid(operands, format=format, block_size=block_size,
                             differential=differential, plan=plan,
                             interpret=interpret)
 
-    if plan.path == "pallas" and plan.fused:
-        # broadcast extras must be VMEM-resident per grid step; past the
-        # budget, degrade to pallas-decode + jnp epilogue instead of failing
-        # Mosaic compilation (docs/kernels.md §TPU notes). Extras the kernel
-        # reads from HBM (dot_score's table) are not broadcast.
-        broadcast_bytes = sum(
-            int(np.prod(v.shape)) * v.dtype.itemsize
-            for k, v in extras.items()
-            if k not in ep.tiled_extras and k not in ep.hbm_extras)
-        if broadcast_bytes <= VMEM_BROADCAST_BUDGET:
-            return eplib.fused_decode(
-                operands, extras, format=format, epilogue=epilogue,
-                block_size=block_size, differential=differential,
-                block_tile=plan.block_tile, chunk_width=plan.chunk,
-                interpret=interpret)
+    plan, downgraded = _run_plan(extras, epilogue=epilogue, plan=plan)
+    if downgraded:
         _obs_counter_inc("decode_plan_downgrade_total", epilogue=epilogue,
                          reason="vmem_broadcast_budget")
-        plan = DecodePlan("pallas", fused=False, block_tile=plan.block_tile,
-                          chunk=plan.chunk)
+    if plan.path == "pallas" and plan.fused:
+        return eplib.fused_decode(
+            operands, extras, format=format, epilogue=epilogue,
+            block_size=block_size, differential=differential,
+            block_tile=plan.block_tile, chunk_width=plan.chunk,
+            interpret=interpret)
     if plan.path == "jnp" and plan.fused:
         return _jnp_fused(operands, extras, format=format, epilogue=epilogue,
                           block_size=block_size, differential=differential,
@@ -411,6 +457,217 @@ def _build_sharded_fn(mesh, axes: tuple, format: str, epilogue: str,
         check_vma=False))
 
 
+# ---------------------------------------------------------------------------
+# per-signature launch cache
+# ---------------------------------------------------------------------------
+# Everything decode() resolves before it launches is a pure function of the
+# call's signature (see _signature), so a repeat call runs a stored entry.
+LAUNCH_CACHE_SIZE = 256
+_LAUNCHES: OrderedDict = OrderedDict()
+_LAUNCHES_LOCK = threading.Lock()
+_GENERATION = 0  # bumped whenever the autotune cache is (re)loaded
+
+
+@dataclass(frozen=True)
+class _Launch:
+    """One cache entry: no arrays, only what the signature fixed."""
+
+    fn: Callable  # takes the format leaves, then the extras, in key order
+    attrs: dict  # the ``decode`` span's attributes
+
+
+def _invalidate_launches():
+    global _GENERATION
+    with _LAUNCHES_LOCK:
+        _GENERATION += 1
+        _LAUNCHES.clear()
+
+
+def _concrete(x) -> bool:
+    return isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
+
+
+def _signature(operands, format, block_size, differential, epilogue,
+               epilogue_operands, plan, interpret):
+    """``(key, leaves)`` of a call the launch cache can serve, else None.
+
+    The key holds everything prepare reads: the static aux, each format
+    leaf's and each extra's shape, dtype and sharding, the epilogue, the
+    plan argument, ``interpret``, the autotune cache's path and generation.
+    Served only when every leaf and extra is a concrete ``jax.Array``:
+    numpy input and tracers under an enclosing ``jit`` take the full path.
+    """
+    if type(operands) is CompressedIntArray:
+        arr_format = operands.format
+        if format is None:
+            format = arr_format
+        elif format != arr_format:
+            return None
+        if block_size is None:
+            block_size = operands.block_size
+        if differential is None:
+            differential = operands.differential
+        names = FORMAT_LEAVES.get(format)
+        if names is None:
+            return None
+        leaves = tuple(getattr(operands, n) for n in names)
+    elif type(operands) is dict:
+        names = FORMAT_LEAVES.get(format)
+        if names is None or block_size is None or differential is None:
+            return None
+        try:
+            leaves = tuple(operands[n] for n in names)
+        except KeyError:
+            return None
+    else:
+        return None
+    extras = epilogue_operands or {}
+    if type(extras) is not dict:
+        return None
+    leaves += tuple(extras.values())
+    if not all(map(_concrete, leaves)):
+        return None
+    if not (plan is None or type(plan) is str or type(plan) is DecodePlan):
+        return None
+    key = (_GENERATION, cache_path(), format, block_size, differential,
+           epilogue, plan, interpret, tuple(extras),
+           tuple((x.shape, x.dtype, x.sharding) for x in leaves))
+    return key, leaves
+
+
+def _store(key, entry: _Launch):
+    with _LAUNCHES_LOCK:
+        if key[0] != _GENERATION:
+            return  # resolved against an autotune cache since replaced
+        _LAUNCHES[key] = entry
+        while len(_LAUNCHES) > LAUNCH_CACHE_SIZE:
+            _LAUNCHES.popitem(last=False)
+
+
+def _lookup(key) -> _Launch | None:
+    entry = _LAUNCHES.get(key)
+    if entry is not None:
+        try:
+            _LAUNCHES.move_to_end(key)
+        except KeyError:  # evicted meanwhile by another thread
+            pass
+    return entry
+
+
+def _normalized(fmt_keys: tuple, leaves: tuple) -> dict:
+    """The format operands with ``[nb, 1]`` counts/bases made ``[nb]``: no
+    op at all for ``[nb]`` metadata, and none per call inside a program."""
+    operands = dict(zip(fmt_keys, leaves))
+    nb = leaves[0].shape[0]
+    operands["counts"] = normalize_block_meta("counts", operands["counts"],
+                                              nb)
+    operands["bases"] = normalize_block_meta("bases", operands["bases"], nb)
+    return operands
+
+
+def _resolve(operands, format, block_size, differential, epilogue,
+             epilogue_operands, plan, interpret) -> tuple[_Launch, tuple]:
+    """The full prepare: unwrap the operands, check them, resolve the plan,
+    detect the mesh. Returns the call's entry and its leaves (the format
+    operands, then the extras)."""
+    if isinstance(operands, CompressedIntArray):
+        arr = operands
+        operands = arr.device_operands()
+        format = arr.format if format is None else format
+        block_size = arr.block_size if block_size is None else block_size
+        differential = (arr.differential if differential is None
+                        else differential)
+    if format is None or block_size is None or differential is None:
+        raise ValueError(
+            "format=/block_size=/differential= are required when "
+            "operands are a raw dict (pass a CompressedIntArray to "
+            "omit them)")
+    if format not in eplib.FORMAT_OPERANDS:
+        raise ValueError(f"unknown format {format!r}; expected one "
+                         f"of {tuple(eplib.FORMAT_OPERANDS)}")
+    ep = eplib.get_epilogue(epilogue)
+    extras = dict(epilogue_operands or {})
+    ep.check(differential, extras)
+    force_sharded = plan == "sharded"
+    p = resolve_plan("auto" if force_sharded else plan, format=format,
+                     epilogue=epilogue, block_size=block_size)
+
+    fmt_keys = FORMAT_LEAVES[format]
+    missing = [k for k in fmt_keys if k not in operands]
+    if missing:
+        raise ValueError(f"format {format!r} operands missing {missing}")
+    nb = operands[fmt_keys[0]].shape[0]
+    operands = {k: operands[k] for k in fmt_keys}
+    operands["counts"] = normalize_block_meta("counts", operands["counts"],
+                                              nb)
+    operands["bases"] = normalize_block_meta("bases", operands["bases"], nb)
+
+    mesh_axes = operand_mesh_axes(operands)
+    extra_keys = tuple(extras)
+    if mesh_axes is not None:
+        mesh, axes = mesh_axes
+        q = extras["query"] if epilogue == "dot_score" else None
+        multi_query = bool(q is not None and q.size // q.shape[-1] > 1)
+        sharded = _build_sharded_fn(mesh, axes, format, epilogue, block_size,
+                                    differential, p, interpret, multi_query,
+                                    tuple(sorted(extras)))
+        n = len(fmt_keys)
+
+        def fn(*leaves):
+            return sharded(_normalized(fmt_keys, leaves[:n]),
+                           dict(zip(extra_keys, leaves[n:])))
+    elif force_sharded:
+        raise ValueError(
+            "plan='sharded' requires operands whose block dimension "
+            "is sharded over a >1-device mesh axis — use "
+            "CompressedIntArray.shard(mesh, axis=...) first")
+    else:
+        run, downgraded = _run_plan(extras, epilogue=epilogue, plan=p)
+        fn = _program(fmt_keys, extra_keys, format, block_size, differential,
+                      epilogue, run, downgraded, interpret)
+    attrs = dict(format=format, plan=p.label, epilogue=epilogue,
+                 blocks=int(nb), chunk=p.chunk, sharded=mesh_axes is not None)
+    return _Launch(fn, attrs), (*operands.values(), *extras.values())
+
+
+@functools.lru_cache(maxsize=128)
+def _program(fmt_keys: tuple, extra_keys: tuple, format: str,
+             block_size: int, differential: bool, epilogue: str,
+             run: DecodePlan, downgraded: bool, interpret: bool | None):
+    """One callable over the leaves, then the extras, with everything
+    static bound in. A single-program plan is one jit of the whole call,
+    metadata normalisation included; an unfused plan keeps its two
+    programs. Cached by statics alone, so the entries of every shape share
+    one jit (and its compiled executables), also across evictions."""
+    n = len(fmt_keys)
+    static = dict(format=format, block_size=block_size,
+                  differential=differential, interpret=interpret)
+    if epilogue == "stream" or (run.fused and run.path in ("pallas", "jnp")):
+        def call(*leaves):
+            return _execute(_normalized(fmt_keys, leaves[:n]),
+                            dict(zip(extra_keys, leaves[n:])),
+                            epilogue=epilogue, plan=run, **static)
+        call.__name__ = f"decode_{format}_{epilogue}"
+        return jax.jit(call)
+
+    def grid_call(*leaves):
+        return _decode_grid(_normalized(fmt_keys, leaves), plan=run,
+                            **static)
+    grid_call.__name__ = f"decode_{format}_grid"
+    grid = jax.jit(grid_call)
+    counts_at = fmt_keys.index("counts")
+
+    def fn(*leaves):
+        if downgraded:
+            _obs_counter_inc("decode_plan_downgrade_total",
+                             epilogue=epilogue,
+                             reason="vmem_broadcast_budget")
+        return _apply_only(grid(*leaves[:n]), leaves[counts_at],
+                           dict(zip(extra_keys, leaves[n:])),
+                           epilogue=epilogue)
+    return fn
+
+
 def decode(
     operands,  # CompressedIntArray, or device_operands()-style dict
     *,
@@ -442,79 +699,42 @@ def decode(
     ``plan="sharded"`` forces that path and raises if operands aren't
     sharded.
 
+    A call on concrete device arrays whose signature was seen before runs
+    the launch cache's entry (module docstring): one lookup, one program
+    call. Counted by ``decode_fastpath_total{result=hit|miss|bypass}``.
+
     Traced as one ``decode`` span over the whole call with two children:
-    ``decode.prepare`` (operand unwrapping, checks, plan resolution, mesh
-    detection) and ``decode.launch`` (the call into the jitted program, up
-    to its return of the not-yet-ready output).
+    ``decode.prepare`` (the cache key and lookup; on a miss or a bypass,
+    operand unwrapping, checks, plan resolution, mesh detection) and
+    ``decode.launch`` (the call into the jitted program, up to its return
+    of the not-yet-ready output).
     """
     with _obs_trace("decode") as span:
         with _obs_trace("decode.prepare"):
-            from repro.core.compressed_array import CompressedIntArray
-
-            if isinstance(operands, CompressedIntArray):
-                arr = operands
-                operands = arr.device_operands()
-                format = arr.format if format is None else format
-                block_size = (arr.block_size if block_size is None
-                              else block_size)
-                differential = (arr.differential if differential is None
-                                else differential)
-            if format is None or block_size is None or differential is None:
-                raise ValueError(
-                    "format=/block_size=/differential= are required when "
-                    "operands are a raw dict (pass a CompressedIntArray to "
-                    "omit them)")
-            if format not in eplib.FORMAT_OPERANDS:
-                raise ValueError(f"unknown format {format!r}; expected one "
-                                 f"of {tuple(eplib.FORMAT_OPERANDS)}")
-            ep = eplib.get_epilogue(epilogue)
-            extras = dict(epilogue_operands or {})
-            ep.check(differential, extras)
-            force_sharded = plan == "sharded"
-            p = resolve_plan("auto" if force_sharded else plan, format=format,
-                             epilogue=epilogue, block_size=block_size)
-
-            fmt_keys = eplib.FORMAT_OPERANDS[format] + ("counts", "bases")
-            missing = [k for k in fmt_keys if k not in operands]
-            if missing:
-                raise ValueError(
-                    f"format {format!r} operands missing {missing}")
-            nb = operands[fmt_keys[0]].shape[0]
-            operands = {k: operands[k] for k in fmt_keys}
-            operands["counts"] = normalize_block_meta(
-                "counts", operands["counts"], nb)
-            operands["bases"] = normalize_block_meta(
-                "bases", operands["bases"], nb)
-
-            mesh_axes = operand_mesh_axes(operands)
-            if mesh_axes is not None:
-                mesh, axes = mesh_axes
-                q = extras["query"] if epilogue == "dot_score" else None
-                multi_query = bool(q is not None
-                                   and q.size // q.shape[-1] > 1)
-                launch = functools.partial(
-                    _build_sharded_fn(mesh, axes, format, epilogue,
-                                      block_size, differential, p, interpret,
-                                      multi_query, tuple(sorted(extras))),
-                    operands, extras)
-            elif force_sharded:
-                raise ValueError(
-                    "plan='sharded' requires operands whose block dimension "
-                    "is sharded over a >1-device mesh axis — use "
-                    "CompressedIntArray.shard(mesh, axis=...) first")
+            call = (operands, format, block_size, differential, epilogue,
+                    epilogue_operands, plan, interpret)
+            sig = _signature(*call)
+            if sig is None:
+                result = "bypass"
+                entry, leaves = _resolve(*call)
             else:
-                launch = functools.partial(
-                    _execute, operands, extras, format=format,
-                    epilogue=epilogue, block_size=block_size,
-                    differential=differential, plan=p, interpret=interpret)
+                key, leaves = sig
+                entry = _lookup(key)
+                result = "hit"
+                if entry is None:
+                    result = "miss"
+                    entry, _ = _resolve(*call)
             if span:
-                span.set(format=format, plan=p.label, epilogue=epilogue,
-                         blocks=int(nb), chunk=p.chunk,
-                         sharded=mesh_axes is not None)
-                _obs_counter_inc("decode_calls_total", plan=p.label,
-                                 format=format, epilogue=epilogue)
+                attrs = entry.attrs
+                span.set(**attrs)
+                _obs_counter_inc("decode_calls_total", plan=attrs["plan"],
+                                 format=attrs["format"], epilogue=epilogue)
+                _obs_counter_inc("decode_fastpath_total", result=result)
         with _obs_trace("decode.launch"):
-            return launch()
+            out = entry.fn(*leaves)
+        if result == "miss":
+            _store(key, entry)
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -124,6 +124,23 @@ def test_default_vbyte_plan_compiles_at_cell_stride(one_chip, tpu_plan):
     _assert_mosaic_kernel(lowered)
 
 
+@pytest.mark.parametrize("column", [False, True])
+def test_launch_cache_program_compiles_at_cell_stride(one_chip, tpu_plan,
+                                                      column):
+    # what a repeat dispatch.decode of a decode cell's list runs: one jit
+    # of the whole call, [n_blocks, 1] metadata normalised inside it
+    plan = tpu_plan("vbyte")
+    meta = _meta(one_chip)
+    if column:
+        meta = {k: _spec(one_chip, (N_BLOCKS, 1), v.dtype)
+                for k, v in meta.items()}
+    leaves = (_format_operands(one_chip, "vbyte", stride=CELL_STRIDE)
+              ["payload"], meta["counts"], meta["bases"])
+    fn = dispatch._program(("payload", "counts", "bases"), (), "vbyte", B,
+                           True, "stream", plan, False, False)
+    _assert_mosaic_kernel(fn.lower(*leaves))
+
+
 @pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("vbyte", 32),
                                        ("streamvbyte", None), ("vbyte", 64)])
 def test_other_routing_widths_compile(one_chip, fmt, chunk):
