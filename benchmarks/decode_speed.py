@@ -201,22 +201,21 @@ def run_fused(n_ints: int = 1 << 18, d: int = 8, vocab: int = 1 << 16,
 def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
                      chunk_widths=(32, 64, 128), block_size: int = 128,
                      interpret_blocks: int = 64) -> list[dict]:
-    """Dense vs banded decode-tile cores on the jnp grid + cost model.
+    """The decode-tile cores on the jnp grid + cost model.
 
     The tracked decode-kernel perf trajectory (``--only decode``): for each
-    format and chunk width the SAME tile-core code that runs inside the
-    Pallas kernels is jitted over the full ``[n_blocks, S]`` grid (pure
-    jnp — XLA-CPU here, XLA-TPU on device), timed against the unchunked
-    core (``chunk_width=None``: compaction for vbyte, dense for the other
-    formats), and paired with the modeled routing MACs / VMEM bytes of one
-    ``[8, S]`` kernel tile (``banded.routing_cost``, whose ``W=None`` row
-    models the dense core, not vbyte's compaction). Pallas interpret-mode
-    rows are appended at
+    format (and, for Stream VByte, each chunk width) the SAME tile-core
+    code that runs inside the Pallas kernels is jitted over the full
+    ``[n_blocks, S]`` grid (pure jnp — XLA-CPU here, XLA-TPU on device),
+    timed against the format's unchunked core (``chunk_width=None``), and
+    paired with the modeled routing MACs / VMEM bytes of one ``[8, S]``
+    kernel tile (``banded.routing_cost``; vbyte's compaction issues no
+    matmul and has no model). Pallas interpret-mode rows are appended at
     a tiny size for coverage and tagged ``interpret: true`` — interpret
     wall time is a correctness artifact, not a perf number, and
     ``benchmarks/report.py`` excludes those rows from headline tables.
     """
-    from repro.kernels.vbyte_decode import banded, ops
+    from repro.kernels.vbyte_decode import ops
     from repro.kernels.vbyte_decode.binpack_kernel import binpack_decode_tile
     from repro.kernels.vbyte_decode.kernel import decode_tile, prefix_sum_tile
     from repro.kernels.vbyte_decode.stream_kernel import stream_decode_tile
@@ -245,8 +244,7 @@ def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
             def make(core_w):
                 @jax.jit
                 def f(payload, counts, bases):
-                    out, valid = decode_tile(payload, counts, block_size=B,
-                                             chunk_width=core_w)
+                    out, valid = decode_tile(payload, counts, block_size=B)
                     return prefix_sum_tile(out, valid, bases)
                 return lambda: f(*fmt_args, counts2, bases2)
         elif fmt == "streamvbyte":
@@ -271,20 +269,18 @@ def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
                 @jax.jit
                 def f(w8, data, counts, bases):
                     out, valid = binpack_decode_tile(w8, data, counts,
-                                                     block_size=B,
-                                                     chunk_width=core_w)
+                                                     block_size=B)
                     return prefix_sum_tile(out, valid, bases)
                 return lambda: f(*fmt_args, counts2, bases2)
 
-        # binpack has no length scan — the chunk axis doesn't exist, so
-        # only the dense core is measured for it
-        widths = [None] + ([] if fmt == "binpack"
-                           else [w for w in chunk_widths if w <= B])
+        # only Stream VByte has a chunk axis: vbyte routes by compaction
+        # and binpack has no length scan
+        widths = [None] + ([w for w in chunk_widths if w <= B]
+                           if fmt == "streamvbyte" else [])
         times = _bench_interleaved(
             {str(w): make(w) for w in widths}, reps)
         t_dense = times["None"]
         for w in widths:
-            cost = banded.routing_cost(fmt, S=S, B=B, W=w, T=8)
             rows.append({
                 "format": fmt,
                 "path": "jnp-grid-core",
@@ -298,35 +294,27 @@ def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
                 "tiles_per_s": round(nb / 8 / times[str(w)], 1),
                 "mis": round(arr.n / times[str(w)] / 1e6, 1),
                 "speedup_vs_dense": round(t_dense / times[str(w)], 2),
-                "modeled_per_tile": {
-                    "mxu_macs": cost["mxu_total"],
-                    "vpu_ops": cost["vpu_total"],
-                    "vmem_bytes": cost["vmem_total"],
-                    "mac_reduction_vs_dense": (
-                        round(banded.routing_reduction(fmt, S=S, B=B, W=w), 2)
-                        if w else 1.0),
-                },
+                "modeled_per_tile": (None if fmt == "vbyte"
+                                     else _modeled_tile(fmt, S, B, w)),
             })
 
         # interpret-mode Pallas coverage rows (tiny size, tagged): the wall
         # time proves nothing about the kernel — keep it out of headlines
         ib = min(interpret_blocks, nb)
         small = {k: jnp.asarray(np.asarray(v)[:ib]) for k, v in od.items()}
-        interp_widths = ((None,) if fmt == "binpack"
-                         else (None, 64 if B >= 64 else 8))
+        interp_widths = ((None, 64 if B >= 64 else 8)
+                         if fmt == "streamvbyte" else (None,))
         for w in interp_widths:
             if fmt == "vbyte":
-                fn = lambda w=w: ops.vbyte_decode_blocked(
-                    **small, block_size=B, differential=True, chunk_width=w,
-                    interpret=True)
+                fn = lambda: ops.vbyte_decode_blocked(
+                    **small, block_size=B, differential=True, interpret=True)
             elif fmt == "streamvbyte":
                 fn = lambda w=w: ops.stream_vbyte_decode_blocked(
                     **small, block_size=B, differential=True, chunk_width=w,
                     interpret=True)
             else:
-                fn = lambda w=w: ops.binpack_decode_blocked(
-                    **small, block_size=B, differential=True, chunk_width=w,
-                    interpret=True)
+                fn = lambda: ops.binpack_decode_blocked(
+                    **small, block_size=B, differential=True, interpret=True)
             t, _ = _bench(fn, reps=2, warmup=1)
             rows.append({
                 "format": fmt,
@@ -340,6 +328,19 @@ def run_decode_cores(n_ints: int = 1 << 18, reps: int = 8,
                 "mis": round(ib * B / t / 1e6, 2),
             })
     return rows
+
+
+def _modeled_tile(fmt: str, S: int, B: int, w: int | None) -> dict:
+    """Modeled routing cost of one ``[8, S]`` tile of an MXU-routed core."""
+    from repro.kernels.vbyte_decode import banded
+
+    cost = banded.routing_cost(fmt, S=S, B=B, W=w, T=8)
+    return {"mxu_macs": cost["mxu_total"],
+            "vpu_ops": cost["vpu_total"],
+            "vmem_bytes": cost["vmem_total"],
+            "mac_reduction_vs_dense": (
+                round(banded.routing_reduction(fmt, S=S, B=B, W=w), 2)
+                if w else 1.0)}
 
 
 def tpu_projection(bits_per_int: float = 16.9) -> dict:

@@ -92,11 +92,10 @@ def main(argv=None) -> int:
 
     arr, docids = make_list(args.k)
     leaves = (arr.payload, arr.counts, arr.bases)
-    p = dispatch.resolve_plan("auto", format="vbyte", epilogue="stream",
+    p = dispatch.resolve_plan("auto", format="vbyte",
                               block_size=arr.block_size)
     static = dict(block_size=arr.block_size, differential=True,
-                  block_tile=p.block_tile, chunk_width=p.chunk,
-                  interpret=None)
+                  block_tile=p.block_tile, interpret=None)
 
     def body(payload, counts, bases):
         return vbyte_decode_blocked(payload, counts, bases, **static)

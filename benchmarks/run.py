@@ -1,8 +1,9 @@
 """Benchmark harness entry point: ``python -m benchmarks.run``.
 
 One benchmark per paper table/figure (+ framework-level extensions):
-  decode             — dense vs banded chunked-scatter decode-tile cores:
-                       tiles/sec + modeled routing MACs/VMEM per plan
+  decode             — the decode-tile cores (Stream VByte dense vs banded
+                       chunked scatter): tiles/sec + modeled routing
+                       MACs/VMEM per plan
                        (interpret-mode rows tagged, excluded from headlines)
   decode_speed       — Fig. 2 (scalar vs masked mis, by posting-list group)
   buffered           — §V last ¶ (decode-to-L1-buffer vs full stream)
@@ -10,7 +11,7 @@ One benchmark per paper table/figure (+ framework-level extensions):
   integrations       — compression of the framework's real id streams
   kernel_check       — Pallas kernel + fused-epilogue parity sweep
                        (+ sharded-vs-single-device parity when >1 device)
-  fused              — fused vs unfused decode→consume epilogues (+ autotune)
+  fused              — fused vs unfused decode→consume epilogues
   serving            — sharded decode throughput + ServingEngine QPS/latency
                        at 1/2/8 forced host devices (subprocess per count)
   index              — inverted-index queries/sec + decoded-ints/sec per
@@ -66,9 +67,9 @@ def bench_kernel_check(quick: bool = False):
                                       other.decode_scalar_oracle()), fmt
                 checked += 1
 
-    # banded-vs-dense parity across (chunk W, block_tile, stride_multiple)
-    # combos: the chunked scatter must be a pure perf knob — identical
-    # uint32 grids for both formats at every geometry
+    # banded-vs-dense parity of Stream VByte's cores across (chunk W,
+    # block_tile, stride_multiple) combos: the chunked scatter must be a
+    # pure perf knob — identical uint32 grids at every geometry
     from repro.kernels.vbyte_decode.dispatch import DecodePlan
 
     combos = ((32, 8, 128),) if quick else (
@@ -77,20 +78,16 @@ def bench_kernel_check(quick: bool = False):
     mixed = (rng.integers(0, 2**63, 700, dtype=np.uint64)
              % (1 << bits.astype(np.uint64))).astype(np.uint64)
     for W, bt, sm in combos:
-        for fmt in ("vbyte", "streamvbyte"):
-            arr = CompressedIntArray.encode(mixed, format=fmt,
-                                            stride_multiple=sm)
-            ops = arr.device_operands()
-            dense = dispatch.decode(ops, format=fmt, block_size=128,
-                                    differential=False,
-                                    plan=DecodePlan("pallas", True, bt))
-            band = dispatch.decode(ops, format=fmt, block_size=128,
-                                   differential=False,
-                                   plan=DecodePlan("pallas", True, bt,
-                                                   chunk=W))
-            assert np.array_equal(np.asarray(dense), np.asarray(band)), \
-                (fmt, W, bt, sm)
-            checked += 1
+        arr = CompressedIntArray.encode(mixed, format="streamvbyte",
+                                        stride_multiple=sm)
+        ops = arr.device_operands()
+        kw = dict(format="streamvbyte", block_size=128, differential=False)
+        dense = dispatch.decode(ops, **kw, plan=DecodePlan("pallas", True, bt))
+        band = dispatch.decode(ops, **kw,
+                               plan=DecodePlan("pallas", True, bt, chunk=W))
+        assert np.array_equal(np.asarray(dense), np.asarray(band)), \
+            (W, bt, sm)
+        checked += 1
 
     # fused epilogue parity: Pallas-fused == jnp-fused == unfused reference
     vals = np.sort(rng.integers(0, 4096, 640)).astype(np.uint64)
@@ -180,7 +177,7 @@ def main():
         # 2^16 (not 2^18): the dense core's grid-level one-hot is
         # O(n·stride·4B) — ~170 MB here, unmanageable at 2^18 on CPU
         n = 1 << 14 if args.quick else 1 << 16
-        print("== decode-tile cores: dense vs banded chunked scatter ==")
+        print("== decode-tile cores (streamvbyte: dense vs banded) ==")
         rows = decode_speed.run_decode_cores(
             n_ints=n, reps=3 if args.quick else 8,
             interpret_blocks=16 if args.quick else 64)
@@ -268,20 +265,6 @@ def main():
                   f"unfused={r['unfused_mis']:>6} mis  "
                   f"speedup={r['fused_speedup']}x{extra}")
         results["fused"] = rows
-        from repro.kernels.vbyte_decode import dispatch
-
-        # quick runs measure tiny sizes — keep their noisy plans out of the
-        # tracked cache that plan="auto" consults
-        cache_file = ("experiments/autotune-quick.json" if args.quick
-                      else dispatch.cache_path())
-        print(f"== autotune: measuring dispatch plans -> {cache_file} ==")
-        cache = dispatch.autotune(
-            n_blocks=8 if args.quick else 64,
-            reps=2 if args.quick else 5,
-            cache_file=cache_file)
-        picks = {k: v["plan"] for k, v in cache.items()}
-        results["autotune"] = picks
-        print(f"  {len(picks)} workload keys cached")
 
     if want("serving"):
         from benchmarks import serving

@@ -88,17 +88,18 @@ def phase_a(seed):
         arr = CompressedIntArray.encode(docids, format=fmt, block_size=128,
                                         differential=True)
         t_enc = time.perf_counter() - t0
-        plan = dispatch.resolve_plan("auto", format=fmt, epilogue="stream",
+        plan = dispatch.resolve_plan("auto", format=fmt,
                                      block_size=arr.block_size)
         check(plan.path == "pallas",
               f"{fmt}: plan 'auto' resolved to {plan.label}, not a Pallas "
               "plan")
         operands = arr.device_operands()
+        # only Stream VByte's cores take a chunk width
+        chunk = {} if plan.chunk is None else {"chunk_width": plan.chunk}
         t0 = time.perf_counter()
         hlo = decode_fns[fmt].lower(
             **operands, block_size=arr.block_size, differential=True,
-            block_tile=plan.block_tile, chunk_width=plan.chunk
-        ).compile().as_text()
+            block_tile=plan.block_tile, **chunk).compile().as_text()
         t_compile = time.perf_counter() - t0
         check("tpu_custom_call" in hlo,
               f"{fmt}: the decode HLO holds no tpu_custom_call (no Mosaic "
@@ -198,7 +199,6 @@ def check_sharded_layout(engine, mesh):
               f"term {tp.term} leaf {name}: blocks per device {rows}, "
               f"expected {leaf.shape[0] // n_dev} on each of {n_dev}")
     plan = dispatch.resolve_plan("auto", format=tp.arr.format,
-                                 epilogue="stream",
                                  block_size=tp.arr.block_size)
     fn = dispatch._build_sharded_fn(
         mesh, ("data",), tp.arr.format, "stream", tp.arr.block_size,

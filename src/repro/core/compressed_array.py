@@ -396,9 +396,10 @@ class CompressedIntArray:
         """Decode on device to the padded uint32[n_blocks, block_size] grid.
 
         ``plan`` is a dispatch plan name or ``DecodePlan``
-        (``repro.kernels.vbyte_decode.dispatch``): ``"auto"`` consults the
-        autotune cache, ``"kernel"``/``"jnp"`` force the Pallas / pure-jnp
-        path, ``"sharded"`` forces the block-parallel ``shard_map`` path
+        (``repro.kernels.vbyte_decode.dispatch``): ``"auto"`` is the
+        backend's default route for the format (``dispatch.default_plan``),
+        ``"kernel"``/``"jnp"`` force the Pallas / pure-jnp path,
+        ``"sharded"`` forces the block-parallel ``shard_map`` path
         (auto-selected anyway when the operands are sharded).
         """
         from repro.kernels.vbyte_decode import dispatch

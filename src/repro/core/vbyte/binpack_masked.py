@@ -70,8 +70,7 @@ def extract_values(lo24: jax.Array, hi16: jax.Array, shift: jax.Array,
     return (val & mask).astype(_U32)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_size", "differential", "chunk_width"))
+@functools.partial(jax.jit, static_argnames=("block_size", "differential"))
 def decode_blocked(
     widths: jax.Array,
     data: jax.Array,
@@ -80,16 +79,12 @@ def decode_blocked(
     *,
     block_size: int,
     differential: bool,
-    chunk_width: int | None = None,
 ) -> jax.Array:
     """Vectorized blocked binpack decode: uint32[n_blocks, block_size].
 
     All blocks decode in parallel at their own width. Zero-padded rows;
-    block b row j valid iff j < counts[b]. ``chunk_width`` is accepted for
-    dispatch-signature parity but ignored: there is no length prefix sum
-    to chunk.
+    block b row j valid iff j < counts[b].
     """
-    del chunk_width  # no scan to decompose — positions are affine in j
     B = block_size
     bitpos = block_bit_positions(widths, B)  # [nb, B]
     lo24, hi16 = gather_words(data, bitpos >> 3)

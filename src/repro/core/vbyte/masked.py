@@ -105,30 +105,7 @@ def decode_stream(
     return out, n_decoded
 
 
-def chunked_exclusive_cumsum(x: jax.Array, chunk_width: int) -> jax.Array:
-    """Exclusive row cumsum computed chunk-by-chunk (the banded structure).
-
-    Identical values to ``cumsum(x) - x`` — the within-chunk prefix plus
-    the sum of earlier chunks is the global prefix — so decoders built on
-    it stay bit-exact with the dense ones by construction. This is the jnp
-    mirror of the Pallas kernels' ``banded.chunked_prefix`` (which runs the
-    same decomposition through [W, W] triangular MXU matmuls).
-    """
-    *lead, S = x.shape
-    W = int(chunk_width)
-    pad = (-S) % W
-    xp = jnp.pad(x, [(0, 0)] * len(lead) + [(0, pad)]) if pad else x
-    nC = xp.shape[-1] // W
-    c = xp.reshape(*lead, nC, W)
-    loc = jnp.cumsum(c, axis=-1, dtype=jnp.int32) - c
-    totals = loc[..., -1] + c[..., -1]
-    base = jnp.cumsum(totals, axis=-1, dtype=jnp.int32) - totals
-    out = (base[..., None] + loc).reshape(*lead, nC * W)
-    return out[..., :S]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_size", "differential", "chunk_width"))
+@functools.partial(jax.jit, static_argnames=("block_size", "differential"))
 def decode_blocked(
     payload: jax.Array,
     counts: jax.Array,
@@ -136,25 +113,19 @@ def decode_blocked(
     *,
     block_size: int,
     differential: bool,
-    chunk_width: int | None = None,
 ) -> jax.Array:
     """Vectorized decode of the blocked layout: uint32[n_blocks, block_size].
 
     All blocks decode in parallel (the SPMD adaptation of the paper's
     sequential 48-byte mask pipeline). Zero-padded rows; block b row j valid
-    iff j < counts[b]. ``chunk_width`` routes the byte→integer prefix sum
-    through the chunked (banded) decomposition the Pallas kernels use —
-    same values bit-for-bit, see ``chunked_exclusive_cumsum``.
+    iff j < counts[b].
     """
     nb, S = payload.shape
     B = block_size
 
     cont = continuation_bits(payload)  # padding zeros ⇒ cont=0 (handled by count mask)
     end = 1 - cont
-    if chunk_width is None:
-        out_idx = jnp.cumsum(end, axis=-1, dtype=jnp.int32) - end
-    else:
-        out_idx = chunked_exclusive_cumsum(end, chunk_width)
+    out_idx = jnp.cumsum(end, axis=-1, dtype=jnp.int32) - end
     pos = in_integer_positions(cont)
     contrib = byte_contributions(payload, pos)
 

@@ -45,18 +45,9 @@ def integer_lengths(codes: jax.Array, counts: jax.Array | None = None) -> jax.Ar
     return jnp.where(j < jnp.asarray(counts, jnp.int32)[..., None], lens, 0)
 
 
-def start_offsets(lengths: jax.Array,
-                  chunk_width: int | None = None) -> jax.Array:
-    """Exclusive prefix sum of lengths: each integer's first data byte.
-
-    ``chunk_width`` computes it through the chunked (banded) decomposition
-    mirroring the Pallas kernels — identical values by construction.
-    """
-    if chunk_width is None:
-        return jnp.cumsum(lengths, axis=-1, dtype=jnp.int32) - lengths
-    from repro.core.vbyte.masked import chunked_exclusive_cumsum
-
-    return chunked_exclusive_cumsum(lengths, chunk_width)
+def start_offsets(lengths: jax.Array) -> jax.Array:
+    """Exclusive prefix sum of lengths: each integer's first data byte."""
+    return jnp.cumsum(lengths, axis=-1, dtype=jnp.int32) - lengths
 
 
 def gather_values(data: jax.Array, starts: jax.Array, lengths: jax.Array) -> jax.Array:
@@ -72,8 +63,7 @@ def gather_values(data: jax.Array, starts: jax.Array, lengths: jax.Array) -> jax
     return contrib.sum(axis=-1, dtype=_U32)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_size", "differential", "chunk_width"))
+@functools.partial(jax.jit, static_argnames=("block_size", "differential"))
 def decode_blocked(
     control: jax.Array,
     data: jax.Array,
@@ -82,18 +72,16 @@ def decode_blocked(
     *,
     block_size: int,
     differential: bool,
-    chunk_width: int | None = None,
 ) -> jax.Array:
     """Vectorized blocked Stream-VByte decode: uint32[n_blocks, block_size].
 
     All blocks decode in parallel. Zero-padded rows; block b row j valid iff
-    j < counts[b]. ``chunk_width`` routes the length prefix sum through the
-    chunked (banded) decomposition — same values bit-for-bit.
+    j < counts[b].
     """
     B = block_size
     codes = control_codes(control, B)  # [nb, B]
     lens = integer_lengths(codes, counts)
-    starts = start_offsets(lens, chunk_width)
+    starts = start_offsets(lens)
     out = gather_values(data, starts, lens)
 
     j = jnp.arange(B, dtype=jnp.int32)[None, :]

@@ -49,10 +49,11 @@ the answer (a union's output):
   descending-bound order, re-checking the bound after each term
   (``QueryStats.probes_pruned`` counts settlements without decode).
 
-Impacts are exact int32, so fused / unfused / sharded / dense / banded
+Impacts are exact int32, so fused / unfused / sharded / Pallas / jnp
 runs are bit-identical; ties break by ascending docid. ``plan=`` is
-forwarded to the dispatch layer, so queries inherit the autotuned plan
-cache, both Pallas/jnp paths, dense and banded cores — and, when a term's
+forwarded to the dispatch layer, so queries inherit its default route
+per format (``dispatch.default_plan``), both Pallas/jnp paths, Stream
+VByte's dense and banded cores — and, when a term's
 ``CompressedIntArray`` is block-sharded over a mesh (``use_skip=False``
 resident-index mode, see ``launch.serve.SearchEngine``), the ``shard_map``
 block-parallel path. :class:`QueryStats` counts decoded vs skipped vs

@@ -1,4 +1,4 @@
-from .dispatch import DecodePlan, autotune, decode, resolve_plan  # noqa: F401
+from .dispatch import DecodePlan, decode, resolve_plan  # noqa: F401
 from .epilogues import EPILOGUES, apply_grid, fused_decode  # noqa: F401
 from .ops import (  # noqa: F401
     binpack_decode_blocked,

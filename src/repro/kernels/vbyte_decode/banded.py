@@ -1,9 +1,9 @@
 """MXU routing primitives of the decode-tile cores.
 
-The banded core is Stream VByte's default routing; Masked VByte takes it
-only by an explicit plan (``plan="banded"`` or a ``chunk`` width), since
-its default routes by VPU compaction with no matmul (``kernel.py``,
-docs/kernels.md §Compaction routing). Binpack uses :func:`onehot_gather`.
+The banded core is Stream VByte's TPU route (``stream_kernel.py``), the
+only decode route with a chunk width. Masked VByte routes by VPU
+compaction with no matmul (``kernel.py``, docs/kernels.md §Compaction
+routing); binpack uses :func:`onehot_gather`.
 
 A dense decode core (Stream VByte's unchunked one) routes bytes to output
 slots with a ``[T, B, S]`` one-hot (every byte against every output) and
@@ -50,8 +50,9 @@ per-slot per-chunk accumulation is a sum of at most 5 halfword pieces
 the f32 results are exact; cross-chunk sums happen after the int32 cast,
 wrapping ≡ mod 2³².
 
-:func:`routing_cost` is the tracked FLOP/VMEM model of dense vs banded
-routing (``benchmarks/run.py --only decode`` persists it per plan).
+:func:`routing_cost` is the tracked FLOP/VMEM model of the MXU routing
+cores, Stream VByte's dense and banded ones and binpack's gather
+(``benchmarks/run.py --only decode`` persists it per plan).
 """
 from __future__ import annotations
 
@@ -82,14 +83,16 @@ def normalize_chunk_width(chunk_width: int, block_size: int) -> int:
 def kernel_name(format: str, chunk_width: int | None,
                 epilogue: str | None = None) -> str:
     """Stable name of a decode ``pallas_call``: its format, its fused
-    epilogue if any, and its routing core (``banded_w<W>``; unchunked,
-    ``compact`` for vbyte and ``dense`` for streamvbyte; ``gather`` for
-    binpack, which has no chunk axis), e.g. ``vbyte_decode_compact`` or
-    ``vbyte_fused_bag_sum_banded_w64``."""
-    if format == "binpack":
+    epilogue if any, and its routing core (``compact`` for vbyte,
+    ``gather`` for binpack; ``dense`` or ``banded_w<W>`` for streamvbyte,
+    the one format with a chunk axis), e.g. ``vbyte_decode_compact`` or
+    ``streamvbyte_fused_bag_sum_banded_w32``."""
+    if format == "vbyte":
+        core = "compact"
+    elif format == "binpack":
         core = "gather"
     elif chunk_width is None:
-        core = "compact" if format == "vbyte" else "dense"
+        core = "dense"
     else:
         core = f"banded_w{int(chunk_width)}"
     stage = "decode" if epilogue is None else f"fused_{epilogue}"
@@ -261,10 +264,11 @@ def routing_cost(format: str, *, S: int, B: int, W: int | None,
     payload/output tiles common to both paths.
 
     ``W=None`` models the dense core. Numbers are per tile of ``T`` blocks;
-    divide by T for per-block, as quoted in docs/kernels.md.
+    divide by T for per-block, as quoted in docs/kernels.md. vbyte has no
+    model: its compaction route issues no matmul.
     """
-    if format not in ("vbyte", "streamvbyte", "binpack"):
-        raise ValueError(f"unknown format {format!r}")
+    if format not in ("streamvbyte", "binpack"):
+        raise ValueError(f"no MXU routing model for format {format!r}")
     f32 = 4
     if format == "binpack":
         # binpack has no length scan, so there is no banded variant (W is
@@ -288,71 +292,45 @@ def routing_cost(format: str, *, S: int, B: int, W: int | None,
             "vmem_total": sum(vmem.values()),
         }
     if W is None:
-        if format == "vbyte":
-            mxu = {
-                "prefix_out_idx": T * S * S,      # [T,S]×[S,S] strict tri
-                "scatter": 2 * T * S * B,         # lo + hi one-hot matmuls
-            }
-            vpu = {"onehot_build": T * S * B}
-            vmem = {
-                "onehot": T * S * B * f32,
-                "tri": S * S * f32,
-            }
-        else:
-            C = B // 4
-            mxu = {
-                "control_expand": T * C * B,      # [T,C]×[C,B] one-hot
-                "prefix_starts": T * B * B,       # [T,B]×[B,B] strict tri
-                "ends_scatter": T * S * B,        # [T,S,B] end-flag one-hot
-                "prefix_out_idx": T * S * S,      # [T,S]×[S,S] strict tri
-                "scatter": 2 * T * S * B,
-            }
-            vpu = {"onehot_build": 2 * T * S * B}  # end flags + scatter
-            vmem = {
-                "onehot": T * S * B * f32,
-                "tri": (B * B + S * S) * f32,
-            }
+        C = B // 4
+        mxu = {
+            "control_expand": T * C * B,      # [T,C]×[C,B] one-hot
+            "prefix_starts": T * B * B,       # [T,B]×[B,B] strict tri
+            "ends_scatter": T * S * B,        # [T,S,B] end-flag one-hot
+            "prefix_out_idx": T * S * S,      # [T,S]×[S,S] strict tri
+            "scatter": 2 * T * S * B,
+        }
+        vpu = {"onehot_build": 2 * T * S * B}  # end flags + scatter
+        vmem = {
+            "onehot": T * S * B * f32,
+            "tri": (B * B + S * S) * f32,
+        }
     else:
         nC = -(-S // W)
         Sp = nC * W
         logB = max(1, math.ceil(math.log2(max(2, B + 1))))
-        if format == "vbyte":
-            mxu = {
-                "prefix_out_idx": T * Sp * W,
-                "scatter": 2 * T * Sp * W,
-            }
-            vpu = {
-                "onehot_build": T * nC * W * W,
-                "band_combine": T * nC * B * logB,
-            }
-            vmem = {
-                "onehot": T * nC * W * W * f32,
-                "tri": W * W * f32,
-                "bands": T * nC * B * f32,
-            }
-        else:
-            ng = -(-B // W)
-            logS = max(1, math.ceil(math.log2(max(2, Sp + 1))))
-            mxu = {
-                # control expand is a static ×4 broadcast in the banded
-                # core — no matmul
-                "prefix_starts": T * ng * W * W,
-                "ends_scatter": T * ng * 4 * W * W,  # [T,4W,W] one-hots
-                "prefix_out_idx": T * Sp * W,
-                "scatter": 2 * T * Sp * W,
-            }
-            vpu = {
-                "ends_band_build": T * ng * W * 4 * W,
-                "ends_place": T * ng * Sp * logS,
-                "onehot_build": T * nC * W * W,
-                "band_combine": T * nC * B * logB,
-            }
-            vmem = {
-                "onehot": T * nC * W * W * f32,
-                "ends_band": T * ng * 4 * W * f32,
-                "tri": W * W * f32,
-                "bands": T * nC * B * f32,
-            }
+        ng = -(-B // W)
+        logS = max(1, math.ceil(math.log2(max(2, Sp + 1))))
+        mxu = {
+            # control expand is a static ×4 broadcast in the banded core —
+            # no matmul
+            "prefix_starts": T * ng * W * W,
+            "ends_scatter": T * ng * 4 * W * W,  # [T,4W,W] one-hots
+            "prefix_out_idx": T * Sp * W,
+            "scatter": 2 * T * Sp * W,
+        }
+        vpu = {
+            "ends_band_build": T * ng * W * 4 * W,
+            "ends_place": T * ng * Sp * logS,
+            "onehot_build": T * nC * W * W,
+            "band_combine": T * nC * B * logB,
+        }
+        vmem = {
+            "onehot": T * nC * W * W * f32,
+            "ends_band": T * ng * 4 * W * f32,
+            "tri": W * W * f32,
+            "bands": T * nC * B * f32,
+        }
     return {
         "mxu_macs": mxu,
         "mxu_total": sum(mxu.values()),

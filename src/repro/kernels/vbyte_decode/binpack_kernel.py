@@ -22,9 +22,8 @@ one-hot gather:
 
 This is why binpack wins on dense low-width gap blocks: the per-tile MXU
 work is two ``[T,B,S]`` contractions and zero routing scans, versus the
-VByte kernels' prefix-sum + scatter pipelines. All tensors live in VMEM;
-``chunk_width`` is accepted for dispatch parity and ignored — there is no
-length scan to chunk.
+VByte kernels' prefix-sum + scatter pipelines. All tensors live in VMEM.
+There is no length scan, so no chunk width either.
 """
 from __future__ import annotations
 
@@ -50,9 +49,7 @@ def _shift_left_cols(x: jax.Array, k: int) -> jax.Array:
 
 
 def binpack_decode_tile(widths: jax.Array, data: jax.Array, counts: jax.Array,
-                        *, block_size: int,
-                        chunk_width: int | None = None
-                        ) -> tuple[jax.Array, jax.Array]:
+                        *, block_size: int) -> tuple[jax.Array, jax.Array]:
     """Decode one VMEM tile of binpack-packed bytes.
 
     ``widths`` is the ``uint8 [T, 1]`` per-block bit-width column, ``data``
@@ -65,7 +62,6 @@ def binpack_decode_tile(widths: jax.Array, data: jax.Array, counts: jax.Array,
     ``ceil(count·w/8) ≤ S`` bytes by construction, so a clamped read only
     feeds bits the width mask discards or lanes the valid mask zeroes.
     """
-    del chunk_width  # positions are affine in j — nothing to chunk
     T, S = data.shape
     B = block_size
 
@@ -103,11 +99,9 @@ def binpack_decode_tile(widths: jax.Array, data: jax.Array, counts: jax.Array,
 
 def _binpack_decode_tile_kernel(widths_ref, data_ref, counts_ref, bases_ref,
                                 out_ref, *, block_size: int,
-                                differential: bool,
-                                chunk_width: int | None):
+                                differential: bool):
     out, valid = binpack_decode_tile(widths_ref[...], data_ref[...],
-                                     counts_ref[...], block_size=block_size,
-                                     chunk_width=chunk_width)
+                                     counts_ref[...], block_size=block_size)
     if differential:
         out = prefix_sum_tile(out, valid, bases_ref[...])
     out_ref[...] = out
@@ -122,7 +116,6 @@ def binpack_decode_blocked_pallas(
     block_size: int,
     differential: bool,
     block_tile: int = 8,
-    chunk_width: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Raw pallas_call wrapper; see ops.binpack_decode_blocked."""
@@ -134,7 +127,7 @@ def binpack_decode_blocked_pallas(
     grid = (nb // block_tile,)
     kernel = functools.partial(
         _binpack_decode_tile_kernel, block_size=block_size,
-        differential=differential, chunk_width=chunk_width,
+        differential=differential,
     )
     return pl.pallas_call(
         kernel,
@@ -148,5 +141,5 @@ def binpack_decode_blocked_pallas(
         out_specs=pl.BlockSpec((block_tile, block_size), lambda g: (g, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.int32),
         interpret=interpret,
-        name=kernel_name("binpack", chunk_width),
+        name=kernel_name("binpack", None),
     )(widths, data, counts, bases)

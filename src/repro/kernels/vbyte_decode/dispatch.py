@@ -1,4 +1,4 @@
-"""Autotuned dispatch for the blocked decode kernels (both formats).
+"""Dispatch for the blocked decode kernels (every format).
 
 Single entry point (:func:`decode`) that picks the execution plan — Pallas
 kernel vs vectorized jnp decoder, fused vs unfused epilogue, ``block_tile``
@@ -16,12 +16,16 @@ A :class:`DecodePlan` names one concrete path:
   grid, then apply the epilogue in a second dispatch (the legacy shape of
   every call site before this layer existed).
 
-``plan="auto"`` consults a small measured autotune cache persisted under
-``experiments/autotune.json`` (:func:`autotune` populates it; run via
-``python -m benchmarks.run --only fused``). With no cache entry the
-heuristic default is the fused path on the current backend. Legacy string
-plans keep old call sites working: ``"kernel"`` → Pallas, ``"jnp"`` → jnp,
-``"fused"``/``"unfused"`` force fusion on the default path.
+**One route per (backend, format).** ``plan="auto"`` is
+:func:`default_plan`, the one place a decode route is chosen: on a TPU the
+fused Pallas plan with 8-block tiles — vbyte routes by VPU compaction,
+binpack by a one-hot gather, Stream VByte by the banded MXU core at
+``chunk=32`` (clamped to the block size) — and on every other backend the
+fused jnp plan. The other plan strings force one part of it:
+``"kernel"`` → Pallas, ``"jnp"`` → jnp, ``"ref"`` → the gather-lowered
+vbyte decoder, ``"fused"``/``"unfused"`` → fusion on the default path,
+``"sharded"`` → the block-parallel path below. A plan whose ``chunk`` is
+set resolves only for Stream VByte's Pallas cores, the one chunked route.
 
 **Sharded block-parallel decode.** Because every block decodes
 independently (per-block ``counts``/``bases`` carry all cross-block
@@ -44,9 +48,10 @@ runs only that entry: one key, one lookup, one program call.
 
 * *Key:* the static aux (format, block size, differential), each format
   leaf's and each extra's name, shape, dtype and sharding, the epilogue,
-  the ``plan`` argument, ``interpret``, the autotune cache's path
-  (``REPRO_AUTOTUNE_CACHE``) and a generation number. Leaves are read off
-  a ``CompressedIntArray`` by ``getattr``, with no ``jnp.asarray``.
+  the ``plan`` argument and ``interpret``. Leaves are read off a
+  ``CompressedIntArray`` by ``getattr``, with no ``jnp.asarray``. The
+  backend is fixed for the life of a process, so the route
+  :func:`default_plan` picks is too.
 * *Entry:* no arrays, only what the signature fixed: the span attributes
   and one callable over the leaves and extras with everything static bound
   in. A single-program plan is one ``jit`` of the whole call, with the
@@ -57,9 +62,6 @@ runs only that entry: one key, one lookup, one program call.
   ``jax.Array``s (numpy input, tracers under an enclosing ``jit``) takes
   the full path and stores nothing, as does a call that raises: a
   malformed call raises on every call.
-* *Invalidation:* reloading the autotune cache (``load_cache(reload=True)``,
-  which :func:`autotune` ends with, or a switch of its path) bumps the
-  generation and drops every entry.
 * *Bound:* an LRU of :data:`LAUNCH_CACHE_SIZE` entries.
 
 ``decode_fastpath_total{result=hit|miss|bypass}`` counts which path each
@@ -68,13 +70,10 @@ call took (only while telemetry is installed).
 from __future__ import annotations
 
 import functools
-import json
-import os
 import threading
-import time
 from collections import OrderedDict
 from collections.abc import Callable
-from dataclasses import asdict, dataclass, replace
+from dataclasses import dataclass, replace
 
 import numpy as np
 
@@ -94,17 +93,6 @@ from . import epilogues as eplib
 from .ops import (binpack_decode_blocked, normalize_block_meta,
                   stream_vbyte_decode_blocked, vbyte_decode_blocked)
 
-# cache lives under the repo's experiments/ dir (resolved relative to this
-# file, NOT the process cwd — library call sites run from anywhere); the
-# REPRO_AUTOTUNE_CACHE env var overrides. Falls back to a cwd-relative path
-# when the source tree layout isn't present (installed package).
-_SRC_DIR = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))))  # <repo>/src in-tree
-DEFAULT_CACHE_PATH = (
-    os.path.join(os.path.dirname(_SRC_DIR), "experiments", "autotune.json")
-    if os.path.basename(_SRC_DIR) == "src"
-    else "experiments/autotune.json")
-
 # broadcast epilogue operands (bag_sum's embedding table, probe sets) above
 # this size cannot be VMEM-resident per grid step on TPU; the fused Pallas
 # plan falls back to pallas-decode + jnp epilogue (docs/kernels.md §TPU
@@ -117,19 +105,17 @@ VMEM_BROADCAST_BUDGET = 4 << 20
 class DecodePlan:
     """One concrete decode execution plan (see module docstring).
 
-    ``chunk`` is the banded-scatter chunk width W: ``None`` runs the
-    format's unchunked routing (VPU compaction for vbyte, the dense O(S·B)
-    one-hot for streamvbyte), an integer W the chunked O(S·W) MXU routing
-    (see ``banded.py``). On the Pallas path it selects the banded tile cores;
-    on the jnp path the chunked prefix decomposition of the vectorized
-    decoders. Both produce bit-identical uint32 grids, so the axis is a
-    pure perf knob — which is why it lives on the autotuned plan.
+    ``chunk`` is the banded-scatter chunk width W of Stream VByte's Pallas
+    cores: ``None`` runs its dense O(S·B) one-hot core, an integer W the
+    chunked O(S·W) MXU core (see ``banded.py``). Both produce bit-identical
+    uint32 grids. No other (format, path) has a chunked route, so
+    :func:`resolve_plan` refuses a chunk anywhere else.
     """
 
     path: str  # "pallas" | "jnp" | "ref" (gather-lowered; GSPMD-friendly)
     fused: bool = True
     block_tile: int = 8
-    chunk: int | None = None  # W; None = the format's unchunked route
+    chunk: int | None = None  # W; Stream VByte's Pallas cores only
 
     def __post_init__(self):
         if self.path not in ("pallas", "jnp", "ref"):
@@ -147,79 +133,29 @@ class DecodePlan:
 
 
 # ---------------------------------------------------------------------------
-# plan resolution + persisted autotune cache
+# plan resolution: the default route table
 # ---------------------------------------------------------------------------
-_CACHE: dict | None = None
-_CACHE_FILE: str | None = None
-
-# Autotune-cache schema version. Bumped to 2 when "binpack" became a third
-# format: older caches were measured in a two-format world (candidate sets,
-# default chunk widths, and cost trade-offs that no longer hold) and carry
-# no schema tag at all, so version-mismatched entries are dropped on load
-# and the plan resolver falls back to the heuristic default instead of
-# mis-resolving from a stale measurement.
-CACHE_SCHEMA = 2
+# chunk width of each format's TPU route. Stream VByte routes by the banded
+# MXU core at W=32, the smallest width that clears the ≥4x modeled
+# routing-MAC reduction at default shapes without shrinking the MXU tiles
+# below usefulness (docs/kernels.md §Banded chunked scatter). vbyte routes
+# by VPU compaction and binpack by a one-hot gather: no chunk axis.
+DEFAULT_CHUNK = {"streamvbyte": 32}
 
 
-def cache_path() -> str:
-    return os.environ.get("REPRO_AUTOTUNE_CACHE", DEFAULT_CACHE_PATH)
-
-
-def cache_key(format: str, epilogue: str, block_size: int,
-              backend: str | None = None) -> str:
-    backend = backend or jax.default_backend()
-    return f"{backend}/{format}/{epilogue}/bs{block_size}"
-
-
-def _migrate_cache(raw: dict) -> dict:
-    """Drop entries from a different (or missing) schema version."""
-    if not isinstance(raw, dict):
-        return {}
-    return {k: v for k, v in raw.items()
-            if isinstance(v, dict) and v.get("schema") == CACHE_SCHEMA}
-
-
-def load_cache(path: str | None = None, *, reload: bool = False) -> dict:
-    global _CACHE, _CACHE_FILE
-    path = path or cache_path()
-    if _CACHE is None or _CACHE_FILE != path or reload:
-        if _CACHE is not None:
-            _invalidate_launches()  # their plans came from the old cache
-        _CACHE_FILE = path
-        try:
-            with open(path) as f:
-                _CACHE = _migrate_cache(json.load(f))
-        except (OSError, ValueError):
-            _CACHE = {}
-    return _CACHE
-
-
-# per-format banded chunk width (plan="banded", autotune candidates): the
-# smallest W that clears the ≥4x modeled routing-MAC reduction at default
-# shapes without shrinking the MXU tiles below usefulness (docs/kernels.md
-# §Banded chunked scatter).
-BANDED_CHUNK = {"vbyte": 64, "streamvbyte": 32}
-# per-format default chunk width of the TPU plan. vbyte routes by VPU
-# compaction, which has no chunk axis (docs/kernels.md §Compaction
-# routing); binpack has no length scan, so no chunk axis either.
-DEFAULT_CHUNK = {"vbyte": None, "streamvbyte": BANDED_CHUNK["streamvbyte"],
-                 "binpack": None}
-
-
-def default_plan(epilogue: str = "stream",
-                 format: str = "vbyte") -> DecodePlan:
-    """Heuristic when the cache has no measurement for a workload."""
+def default_plan(format: str) -> DecodePlan:
+    """The decode route for ``format`` on this backend (``plan="auto"``)."""
     if jax.default_backend() == "tpu":
         return DecodePlan("pallas", fused=True, block_tile=8,
-                          chunk=DEFAULT_CHUNK.get(format, 64))
+                          chunk=DEFAULT_CHUNK.get(format))
     # CPU proxy: interpret-mode Pallas is a correctness path, not a perf
-    # path; the jnp decoders vectorize through XLA-CPU. Fusion still wins
-    # (one executable, no id-stream round-trip) — see benchmarks.json.
+    # path; the jnp decoders vectorize through XLA-CPU, and fusion keeps
+    # the decoded grid inside one executable.
     return DecodePlan("jnp", fused=True)
 
 
 def _clamp_chunk(chunk: int | None, block_size: int) -> int | None:
-    """Shrink a heuristic chunk width to the workload's block size (a band
+    """Shrink a default chunk width to the workload's block size (a band
     can't be wider than the output row); None when no multiple of 8 fits."""
     if chunk is None or chunk <= block_size:
         return chunk
@@ -227,40 +163,31 @@ def _clamp_chunk(chunk: int | None, block_size: int) -> int | None:
     return clamped or None
 
 
-def resolve_plan(plan, *, format: str, epilogue: str,
-                 block_size: int) -> DecodePlan:
+def resolve_plan(plan, *, format: str, block_size: int) -> DecodePlan:
+    """The :class:`DecodePlan` that a ``plan=`` argument names for
+    ``format`` (module docstring)."""
     if isinstance(plan, DecodePlan):
-        return plan
-    if plan in (None, "auto"):
-        entry = load_cache().get(cache_key(format, epilogue, block_size))
-        if entry and "plan" in entry:
-            _obs_counter_inc("plan_cache_total", result="hit")
-            p = entry["plan"]
-            return DecodePlan(p["path"], p["fused"], p.get("block_tile", 8),
-                              p.get("chunk"))
-        _obs_counter_inc("plan_cache_total", result="miss")
-        d = default_plan(epilogue, format)
-        return replace(d, chunk=_clamp_chunk(d.chunk, block_size))
-    if plan in ("kernel", "pallas"):
-        return DecodePlan("pallas", fused=True)
-    if plan == "jnp":
-        return DecodePlan("jnp", fused=True)
-    if plan == "ref":
-        return DecodePlan("ref", fused=False)
-    if plan == "fused":
-        return DecodePlan(default_plan(epilogue, format).path, fused=True)
-    if plan == "unfused":
-        return DecodePlan(default_plan(epilogue, format).path, fused=False)
-    if plan == "banded":
-        return replace(default_plan(epilogue, format),
-                       chunk=_clamp_chunk(BANDED_CHUNK.get(format),
-                                          block_size))
-    if plan == "dense":
-        return replace(default_plan(epilogue, format), chunk=None)
-    raise ValueError(
-        f"unknown plan {plan!r}; expected a DecodePlan or one of "
-        "'auto', 'kernel', 'pallas', 'jnp', 'fused', 'unfused', "
-        "'banded', 'dense'")
+        p = plan
+    elif plan in (None, "auto"):
+        d = default_plan(format)
+        p = replace(d, chunk=_clamp_chunk(d.chunk, block_size))
+    elif plan == "kernel":
+        p = DecodePlan("pallas", fused=True)
+    elif plan == "jnp":
+        p = DecodePlan("jnp", fused=True)
+    elif plan == "ref":
+        p = DecodePlan("ref", fused=False)
+    elif plan in ("fused", "unfused"):
+        p = DecodePlan(default_plan(format).path, fused=plan == "fused")
+    else:
+        raise ValueError(
+            f"unknown plan {plan!r}; expected a DecodePlan or one of "
+            "'auto', 'kernel', 'jnp', 'ref', 'fused', 'unfused', 'sharded'")
+    if p.chunk is not None and (format, p.path) != ("streamvbyte", "pallas"):
+        raise ValueError(
+            f"plan {p.label} sets a chunk width, but only Stream VByte's "
+            f"Pallas cores have a chunked route (format={format!r})")
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -272,11 +199,11 @@ def _decode_grid(operands: dict, *, format: str, block_size: int,
     """Step-1 decode to the uint32 [n_blocks, block_size] grid."""
     if plan.path == "pallas":
         fn = {"vbyte": vbyte_decode_blocked,
-              "streamvbyte": stream_vbyte_decode_blocked,
+              "streamvbyte": functools.partial(stream_vbyte_decode_blocked,
+                                               chunk_width=plan.chunk),
               "binpack": binpack_decode_blocked}[format]
         return fn(**operands, block_size=block_size, differential=differential,
-                  block_tile=plan.block_tile, chunk_width=plan.chunk,
-                  interpret=interpret)
+                  block_tile=plan.block_tile, interpret=interpret)
     if plan.path == "ref":
         if format != "vbyte":
             raise ValueError(
@@ -293,17 +220,15 @@ def _decode_grid(operands: dict, *, format: str, block_size: int,
     dec = {"vbyte": vmasked.decode_blocked,
            "streamvbyte": svb_masked.decode_blocked,
            "binpack": bpk_masked.decode_blocked}[format]
-    return dec(**operands, block_size=block_size, differential=differential,
-               chunk_width=plan.chunk)
+    return dec(**operands, block_size=block_size, differential=differential)
 
 
 @functools.partial(
     jax.jit, static_argnames=("format", "epilogue", "block_size",
-                              "differential", "chunk_width")
+                              "differential")
 )
 def _jnp_fused(operands: dict, extras: dict, *, format: str, epilogue: str,
-               block_size: int, differential: bool,
-               chunk_width: int | None = None):
+               block_size: int, differential: bool):
     """Fused CPU path: decode + epilogue in ONE XLA executable.
 
     The optimization barrier pins the decoded grid as a fusion boundary:
@@ -315,8 +240,7 @@ def _jnp_fused(operands: dict, extras: dict, *, format: str, epilogue: str,
     dec = {"vbyte": vmasked.decode_blocked,
            "streamvbyte": svb_masked.decode_blocked,
            "binpack": bpk_masked.decode_blocked}[format]
-    grid = dec(**operands, block_size=block_size, differential=differential,
-               chunk_width=chunk_width)
+    grid = dec(**operands, block_size=block_size, differential=differential)
     grid = lax.optimization_barrier(grid)
     return eplib.apply_grid(epilogue, grid, operands["counts"], extras)
 
@@ -375,8 +299,7 @@ def _execute(operands: dict, extras: dict, *, format: str, epilogue: str,
             interpret=interpret)
     if plan.path == "jnp" and plan.fused:
         return _jnp_fused(operands, extras, format=format, epilogue=epilogue,
-                          block_size=block_size, differential=differential,
-                          chunk_width=plan.chunk)
+                          block_size=block_size, differential=differential)
     # unfused: decode grid, then the epilogue as a second dispatch
     grid = _decode_grid(operands, format=format, block_size=block_size,
                         differential=differential, plan=plan,
@@ -465,7 +388,6 @@ def _build_sharded_fn(mesh, axes: tuple, format: str, epilogue: str,
 LAUNCH_CACHE_SIZE = 256
 _LAUNCHES: OrderedDict = OrderedDict()
 _LAUNCHES_LOCK = threading.Lock()
-_GENERATION = 0  # bumped whenever the autotune cache is (re)loaded
 
 
 @dataclass(frozen=True)
@@ -474,13 +396,6 @@ class _Launch:
 
     fn: Callable  # takes the format leaves, then the extras, in key order
     attrs: dict  # the ``decode`` span's attributes
-
-
-def _invalidate_launches():
-    global _GENERATION
-    with _LAUNCHES_LOCK:
-        _GENERATION += 1
-        _LAUNCHES.clear()
 
 
 def _concrete(x) -> bool:
@@ -493,7 +408,7 @@ def _signature(operands, format, block_size, differential, epilogue,
 
     The key holds everything prepare reads: the static aux, each format
     leaf's and each extra's shape, dtype and sharding, the epilogue, the
-    plan argument, ``interpret``, the autotune cache's path and generation.
+    plan argument and ``interpret``.
     Served only when every leaf and extra is a concrete ``jax.Array``:
     numpy input and tracers under an enclosing ``jit`` take the full path.
     """
@@ -529,16 +444,14 @@ def _signature(operands, format, block_size, differential, epilogue,
         return None
     if not (plan is None or type(plan) is str or type(plan) is DecodePlan):
         return None
-    key = (_GENERATION, cache_path(), format, block_size, differential,
-           epilogue, plan, interpret, tuple(extras),
+    key = (format, block_size, differential, epilogue, plan, interpret,
+           tuple(extras),
            tuple((x.shape, x.dtype, x.sharding) for x in leaves))
     return key, leaves
 
 
 def _store(key, entry: _Launch):
     with _LAUNCHES_LOCK:
-        if key[0] != _GENERATION:
-            return  # resolved against an autotune cache since replaced
         _LAUNCHES[key] = entry
         while len(_LAUNCHES) > LAUNCH_CACHE_SIZE:
             _LAUNCHES.popitem(last=False)
@@ -590,7 +503,7 @@ def _resolve(operands, format, block_size, differential, epilogue,
     ep.check(differential, extras)
     force_sharded = plan == "sharded"
     p = resolve_plan("auto" if force_sharded else plan, format=format,
-                     epilogue=epilogue, block_size=block_size)
+                     block_size=block_size)
 
     fmt_keys = FORMAT_LEAVES[format]
     missing = [k for k in fmt_keys if k not in operands]
@@ -735,154 +648,3 @@ def decode(
         if result == "miss":
             _store(key, entry)
         return out
-
-
-# ---------------------------------------------------------------------------
-# measured autotune
-# ---------------------------------------------------------------------------
-def _time_call(fn, *, reps: int, warmup: int) -> float:
-    for _ in range(warmup):
-        jax.block_until_ready(fn())
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        out = jax.block_until_ready(fn())
-    del out
-    return (time.perf_counter() - t0) / reps
-
-
-def _synthetic_workload(format: str, *, n_blocks: int, block_size: int,
-                        vocab: int, d: int, seed: int):
-    from repro.core.compressed_array import CompressedIntArray
-
-    rng = np.random.default_rng(seed)
-    n = n_blocks * block_size
-    values = np.sort(rng.integers(0, vocab, size=n)).astype(np.uint64)
-    arr = CompressedIntArray.encode(values, format=format,
-                                    block_size=block_size, differential=True)
-    operands = arr.device_operands()
-    nb = arr.n_blocks
-    probe = jnp.asarray(np.sort(rng.choice(vocab, size=min(128, vocab),
-                                           replace=False))
-                        .astype(np.int32)[None, :])
-    # aligned per-posting weight stream (quantized impacts): same block
-    # layout as the main array, non-differential, values < 2^8
-    impacts = rng.integers(1, 256, size=n).astype(np.uint64)
-    imp_arr = CompressedIntArray.encode(impacts, format=format,
-                                        block_size=block_size,
-                                        differential=False)
-    w_ops = {f"w_{k}": v for k, v in imp_arr.device_operands().items()
-             if k in ("payload", "control", "data", "widths")}
-    extras = {
-        "bag_sum": {"table": jnp.asarray(
-            rng.standard_normal((vocab, d)).astype(np.float32))},
-        "dot_score": {"table": jnp.asarray(
-            rng.standard_normal((vocab, d)).astype(np.float32)),
-            "query": jnp.asarray(
-                rng.standard_normal((1, d)).astype(np.float32))},
-        "adjacency_rebase": {"edge_base": jnp.asarray(
-            rng.integers(0, vocab, (nb, block_size)).astype(np.int32))},
-        "membership": {"probe": probe},
-        "bm25_accum": {"probe": probe,
-                       "impact": jnp.asarray([[7]], jnp.int32)},
-        "membership_rows": {"probe": jnp.asarray(
-            rng.integers(0, vocab, (nb, 1)).astype(np.int32))},
-        "bm25_accum_rows": {"probe": jnp.asarray(
-            rng.integers(0, vocab, (nb, 1)).astype(np.int32)),
-            "impact": jnp.asarray([[7]], jnp.int32)},
-        "bm25_weighted": {"probe": probe, **w_ops},
-        "bm25_weighted_rows": {"probe": jnp.asarray(
-            rng.integers(0, vocab, (nb, 1)).astype(np.int32)), **w_ops},
-        "stream": {},
-        "checksum": {},
-    }
-    return operands, extras, arr.bits_per_int
-
-
-def autotune(
-    *,
-    formats=("vbyte", "streamvbyte", "binpack"),
-    epilogue_names=("stream", "bag_sum", "dot_score", "adjacency_rebase",
-                    "membership", "bm25_accum", "membership_rows",
-                    "bm25_accum_rows", "bm25_weighted",
-                    "bm25_weighted_rows", "checksum"),
-    block_size: int = 128,
-    n_blocks: int = 64,
-    vocab: int = 4096,
-    d: int = 64,
-    reps: int = 5,
-    warmup: int = 2,
-    include_pallas: bool | None = None,
-    cache_file: str | None = None,
-    seed: int = 0,
-) -> dict:
-    """Measure candidate plans per (format, epilogue) and persist the best.
-
-    On CPU the Pallas candidates run in interpret mode (orders of magnitude
-    off their Mosaic speed), so they are excluded unless ``include_pallas``
-    is forced — the CPU cache then records the jnp fused-vs-unfused choice,
-    and a TPU run of the same function writes its own keys.
-    """
-    backend = jax.default_backend()
-    if include_pallas is None:
-        include_pallas = backend == "tpu"
-    cache_file = cache_file or cache_path()
-    cache = dict(load_cache(cache_file))
-
-    for fmt in formats:
-        operands, extras_by_ep, bits = _synthetic_workload(
-            fmt, n_blocks=n_blocks, block_size=block_size, vocab=vocab, d=d,
-            seed=seed)
-        for ep_name in epilogue_names:
-            if ep_name == "stream":
-                # no consumer: fused vs unfused is the same program — the
-                # decoder path, block tile and banded chunk width are the
-                # real degrees of freedom
-                w0 = BANDED_CHUNK.get(fmt)
-                candidates = [DecodePlan("jnp", True),
-                              DecodePlan("jnp", True, chunk=w0)]
-                if fmt == "vbyte":
-                    candidates.append(DecodePlan("ref", False))
-                if include_pallas:
-                    candidates += [DecodePlan("pallas", True, bt, chunk=w)
-                                   for bt in (8, 16)
-                                   for w in dict.fromkeys((None, 32, w0))]
-                    # the banded cores' smaller one-hot/triangular VMEM
-                    # footprint is what makes tiles past 8 blocks fit
-                    candidates += [DecodePlan("pallas", True, 32, chunk=w0)]
-            else:
-                w0 = BANDED_CHUNK.get(fmt)
-                candidates = [DecodePlan("jnp", True), DecodePlan("jnp", False),
-                              DecodePlan("jnp", True, chunk=w0)]
-                if include_pallas:
-                    candidates += [DecodePlan("pallas", True, bt, chunk=w)
-                                   for bt in (8, 16) for w in (None, w0)]
-                    candidates += [DecodePlan("pallas", True, 32, chunk=w0),
-                                   DecodePlan("pallas", False, 8)]
-            # binpack has no chunk axis (BANDED_CHUNK has no width), which
-            # collapses banded candidates onto their dense twins — dedupe
-            candidates = list({c.label: c for c in candidates}.values())
-            timings = {}
-            for cand in candidates:
-                fn = functools.partial(
-                    decode, operands, format=fmt, block_size=block_size,
-                    differential=True, epilogue=ep_name,
-                    epilogue_operands=extras_by_ep[ep_name], plan=cand)
-                timings[cand.label] = round(
-                    _time_call(fn, reps=reps, warmup=warmup) * 1e3, 4)
-            best = min(candidates, key=lambda c: timings[c.label])
-            cache[cache_key(fmt, ep_name, block_size, backend)] = {
-                "schema": CACHE_SCHEMA,
-                "plan": asdict(best),
-                "candidates_ms": timings,
-                "backend": backend,
-                "workload": {"n_blocks": n_blocks, "block_size": block_size,
-                             "vocab": vocab, "d": d,
-                             "bits_per_int": round(bits, 2)},
-                "measured_at": time.strftime("%Y-%m-%d %H:%M:%S"),
-            }
-
-    os.makedirs(os.path.dirname(cache_file) or ".", exist_ok=True)
-    with open(cache_file, "w") as f:
-        json.dump(cache, f, indent=1, sort_keys=True)
-    load_cache(cache_file, reload=True)
-    return cache

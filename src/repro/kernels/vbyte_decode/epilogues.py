@@ -196,21 +196,18 @@ def _decode_weight_tile(valid, w_payload=None, w_control=None, w_data=None,
     the weight tile's count vector — no extra metadata operands. The
     format discriminator is which operands arrived: ``w_widths`` → binpack,
     ``w_payload`` → vbyte, ``w_control``+``w_data`` → streamvbyte. Always
-    decodes with the format's unchunked routing (``chunk_width=None``:
-    compaction for vbyte, dense for streamvbyte): the weight stride is
-    short (impacts are < 2^impact_bits) and the tile cores are bit-exact
-    for any routing geometry.
+    decodes with the format's unchunked routing (for streamvbyte the dense
+    core): the weight stride is short (impacts are < 2^impact_bits) and
+    the tile cores are bit-exact for any routing geometry.
     """
     counts = valid.astype(jnp.int32).sum(axis=1, keepdims=True)
     B = valid.shape[-1]
     if w_widths is not None and w_data is not None:
-        w, _ = binpack_decode_tile(w_widths, w_data, counts,
-                                   block_size=B, chunk_width=None)
+        w, _ = binpack_decode_tile(w_widths, w_data, counts, block_size=B)
     elif w_payload is not None:
-        w, _ = decode_tile(w_payload, counts, block_size=B, chunk_width=None)
+        w, _ = decode_tile(w_payload, counts, block_size=B)
     elif w_control is not None and w_data is not None:
-        w, _ = stream_decode_tile(w_control, w_data, counts,
-                                  block_size=B, chunk_width=None)
+        w, _ = stream_decode_tile(w_control, w_data, counts, block_size=B)
     else:
         raise ValueError(
             "weighted epilogue needs w_payload (vbyte), "
@@ -389,17 +386,21 @@ def apply_grid(epilogue: str, grid_u32: jax.Array, counts: jax.Array,
 def _tile_decoder(format: str, *, block_size: int, differential: bool,
                   chunk_width: int | None):
     """``decode(*fmt_tiles, counts, bases) -> (vals, valid)``: the format's
-    decode-tile core on one VMEM tile, then the differential prefix sum."""
+    decode-tile core on one VMEM tile, then the differential prefix sum.
+    ``chunk_width`` selects Stream VByte's banded core, the only chunked
+    one."""
+    if chunk_width is not None and format != "streamvbyte":
+        raise ValueError(f"format {format!r} has no chunked route; "
+                         f"got chunk_width={chunk_width}")
+
     def decode(*tiles):
         *fmt_tiles, counts, bases = tiles
         if format == "vbyte":
             vals, valid = decode_tile(*fmt_tiles, counts,
-                                      block_size=block_size,
-                                      chunk_width=chunk_width)
+                                      block_size=block_size)
         elif format == "binpack":
             vals, valid = binpack_decode_tile(*fmt_tiles, counts,
-                                              block_size=block_size,
-                                              chunk_width=chunk_width)
+                                              block_size=block_size)
         else:
             vals, valid = stream_decode_tile(*fmt_tiles, counts,
                                              block_size=block_size,

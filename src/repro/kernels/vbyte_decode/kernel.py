@@ -25,10 +25,6 @@ differential epilogue keeps 32-bit exactness on an f32 MXU by splitting every
 ``precision=HIGHEST``) and are recombined with wrap-around int32 adds
 (≡ mod 2^32, i.e. uint32).
 
-``chunk_width=W`` selects the chunked banded MXU scatter instead
-(``banded.py``): the A/B baseline of the compaction route and an autotune
-candidate, bit-identical output (docs/kernels.md §Banded chunked scatter).
-
 All tensors live in VMEM; block dims are multiples of (8, 128) lanes.
 """
 from __future__ import annotations
@@ -40,8 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .banded import (banded_scatter_u32, chunked_prefix, exact_dot,
-                     kernel_name, normalize_chunk_width, split_cols)
+from .banded import exact_dot, kernel_name
 
 
 def _shift_right(x: jax.Array, k: int) -> jax.Array:
@@ -143,8 +138,8 @@ def _row_cumsum_exact_u32(x: jax.Array, incl_tri: jax.Array) -> jax.Array:
     return lo + (hi << 16)
 
 
-def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
-                chunk_width: int | None = None) -> tuple[jax.Array, jax.Array]:
+def decode_tile(payload: jax.Array, counts: jax.Array, *,
+                block_size: int) -> tuple[jax.Array, jax.Array]:
     """Decode one VMEM tile of Masked-VByte bytes — the shared decode-tile core.
 
     ``payload`` is the raw ``uint8 [T, S]`` tile, ``counts`` the ``int32
@@ -153,15 +148,9 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     ``[T, B]``. Pure jnp/lax — callable both from a Pallas kernel body and
     from host-level code; every fused epilogue consumes this contract.
 
-    ``chunk_width=None`` runs the compaction routing: integers are
-    assembled at their terminator bytes and compacted left on the VPU in
-    ⌈log₁₆ S⌉ rounds of static lane shifts — integer arithmetic, no
-    matmul. An
-    integer ``W`` selects the chunked banded-scatter routing
-    (``banded.py``): out_idx is monotone and increments ≤1 per byte, so
-    chunk ``c``'s bytes land only in slots ``[chunk_base[c],
-    chunk_base[c]+W)`` — O(S·W) MXU MACs, identical uint32 output
-    bit-for-bit.
+    Routing is compaction: integers are assembled at their terminator
+    bytes and compacted left on the VPU in ⌈log₁₆ S⌉ rounds of static lane
+    shifts — integer arithmetic, no matmul.
     """
     T, S = payload.shape
     B = block_size
@@ -171,8 +160,7 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
     end = 1 - cont
 
     # within-integer byte position (≤ 4): closed form over preceding cont
-    # flags — static shifts over the full row, so integers whose bytes
-    # straddle a chunk boundary see their true position either way
+    # flags — static shifts over the full row
     c1 = _shift_right(cont, 1)
     c2 = _shift_right(cont, 2)
     c3 = _shift_right(cont, 3)
@@ -181,35 +169,18 @@ def decode_tile(payload: jax.Array, counts: jax.Array, *, block_size: int,
 
     contrib = (b & 0x7F) << (7 * pos)  # int32, wraps ≡ uint32
 
-    if chunk_width is None:
-        # compaction routing: the integer ending at terminator i goes to
-        # slot #terminators before i, i.e. i moves left by its count of
-        # earlier continuation bytes; other lanes are dead (value, shift 0).
-        # Terminators past the block's count (padding zeros) need no mask:
-        # they land on slots ≥ count, which the valid mask below zeroes.
-        term = end == 1
-        val = jnp.where(term, _terminator_values(contrib, (c1, c2, c3, c4)),
-                        0)
-        shift = jnp.where(term, _row_prefix_count(cont) - cont, 0)
-        out = _compact_left(val, shift)
-        if S < B:
-            out = jnp.concatenate(
-                [out, jnp.zeros((T, B - S), out.dtype)], axis=1)
-        out = out[:, :B]
-    else:
-        W = normalize_chunk_width(chunk_width, B)
-        # chunked prefix: loc = #terminators earlier in the chunk (the
-        # within-band slot, < W by construction), base = #terminators in
-        # earlier chunks. Padding flags are zeros, so bases are unaffected.
-        loc, base = chunked_prefix(end, W)
-        lo, hi = [], []
-        for lc, bc, cc in zip(loc, base, split_cols(contrib, W)):
-            cc = jnp.where(bc + lc < counts, cc, 0)  # keep: out_idx < count
-            lo.append(cc & 0xFFFF)
-            hi.append((cc >> 16) & 0xFFFF)
-        # banded one-hot scatter into W-slot bands + barrel-shift combine;
-        # straddling integers recombine via the overlapped int32 band add
-        out = banded_scatter_u32(loc, lo, hi, base, B)
+    # compaction routing: the integer ending at terminator i goes to slot
+    # #terminators before i, i.e. i moves left by its count of earlier
+    # continuation bytes; other lanes are dead (value, shift 0). Terminators
+    # past the block's count (padding zeros) need no mask: they land on
+    # slots ≥ count, which the valid mask below zeroes.
+    term = end == 1
+    val = jnp.where(term, _terminator_values(contrib, (c1, c2, c3, c4)), 0)
+    shift = jnp.where(term, _row_prefix_count(cont) - cont, 0)
+    out = _compact_left(val, shift)
+    if S < B:
+        out = jnp.concatenate([out, jnp.zeros((T, B - S), out.dtype)], axis=1)
+    out = out[:, :B]
 
     jrow = lax.broadcasted_iota(jnp.int32, (T, B), 1)
     valid = jrow < counts
@@ -232,10 +203,9 @@ def prefix_sum_tile(out: jax.Array, valid: jax.Array, bases: jax.Array) -> jax.A
 
 
 def _decode_tile_kernel(payload_ref, counts_ref, bases_ref, out_ref, *,
-                        block_size: int, differential: bool,
-                        chunk_width: int | None):
+                        block_size: int, differential: bool):
     out, valid = decode_tile(payload_ref[...], counts_ref[...],
-                             block_size=block_size, chunk_width=chunk_width)
+                             block_size=block_size)
     if differential:
         out = prefix_sum_tile(out, valid, bases_ref[...])
     out_ref[...] = out
@@ -249,7 +219,6 @@ def decode_blocked_pallas(
     block_size: int,
     differential: bool,
     block_tile: int = 8,
-    chunk_width: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Raw pallas_call wrapper; see ops.vbyte_decode_blocked for the public API."""
@@ -259,7 +228,6 @@ def decode_blocked_pallas(
     grid = (nb // block_tile,)
     kernel = functools.partial(
         _decode_tile_kernel, block_size=block_size, differential=differential,
-        chunk_width=chunk_width,
     )
     return pl.pallas_call(
         kernel,
@@ -272,5 +240,5 @@ def decode_blocked_pallas(
         out_specs=pl.BlockSpec((block_tile, block_size), lambda g: (g, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size), jnp.int32),
         interpret=interpret,
-        name=kernel_name("vbyte", chunk_width),
+        name=kernel_name("vbyte", None),
     )(payload, counts, bases)

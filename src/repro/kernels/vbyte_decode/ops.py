@@ -65,7 +65,7 @@ def normalize_probe(probe, width: int):
 
 @functools.partial(
     jax.jit, static_argnames=("block_size", "differential", "block_tile",
-                              "chunk_width", "interpret")
+                              "interpret")
 )
 def vbyte_decode_blocked(
     payload: jax.Array,  # uint8 [n_blocks, stride]
@@ -75,7 +75,6 @@ def vbyte_decode_blocked(
     block_size: int,
     differential: bool,
     block_tile: int = 8,
-    chunk_width: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Decode a blocked VByte payload to uint32[n_blocks, block_size]."""
@@ -101,7 +100,6 @@ def vbyte_decode_blocked(
         block_size=block_size,
         differential=differential,
         block_tile=block_tile,
-        chunk_width=chunk_width,
         interpret=interpret,
     )
     out = jax.lax.bitcast_convert_type(out, jnp.uint32)
@@ -158,7 +156,7 @@ def stream_vbyte_decode_blocked(
 
 @functools.partial(
     jax.jit, static_argnames=("block_size", "differential", "block_tile",
-                              "chunk_width", "interpret")
+                              "interpret")
 )
 def binpack_decode_blocked(
     widths: jax.Array,  # uint8 [n_blocks, 1] or [n_blocks]
@@ -169,7 +167,6 @@ def binpack_decode_blocked(
     block_size: int,
     differential: bool,
     block_tile: int = 8,
-    chunk_width: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Decode a blocked binpack payload to uint32[n_blocks, block_size]."""
@@ -198,7 +195,6 @@ def binpack_decode_blocked(
         block_size=block_size,
         differential=differential,
         block_tile=block_tile,
-        chunk_width=chunk_width,
         interpret=interpret,
     )
     out = jax.lax.bitcast_convert_type(out, jnp.uint32)

@@ -28,9 +28,10 @@ else in this repo.
 
 ``chunk_width=W`` replaces the O(S·B) flag/scatter routing above with the
 chunked banded scatter: per-integer end flags are banded into
-byte space (a W-integer chunk spans ≤ 4W data bytes), after which the
-byte→integer machinery is exactly the Masked-VByte banded core — O(S·W)
-MACs, bit-identical output (docs/kernels.md §Banded chunked scatter).
+byte space (a W-integer chunk spans ≤ 4W data bytes), after which bytes
+route to integers by ``banded.py``'s chunked prefix and banded one-hot
+scatter — O(S·W) MACs, bit-identical output (docs/kernels.md §Banded
+chunked scatter). It is this format's TPU default (``chunk=32``).
 """
 from __future__ import annotations
 
@@ -70,10 +71,10 @@ def stream_decode_tile(control: jax.Array, data: jax.Array, counts: jax.Array,
     scatter matmuls. An integer ``W`` selects the chunked banded routing:
     per-integer **end flags** are scattered into byte space through narrow
     ``[T, 4W, W]`` one-hot bands per integer chunk (a chunk of W integers
-    spans ≤ 4W data bytes), after which the byte→integer machinery is
-    exactly the Masked-VByte banded core — chunked prefix of the end flags,
-    closed-form in-integer positions, ``[T, W, W]`` banded scatter per byte
-    chunk. O(S·W) instead of O(S·B), identical uint32 output bit-for-bit.
+    spans ≤ 4W data bytes), after which bytes route to integers by the
+    chunked prefix of the end flags, closed-form in-integer positions and
+    a ``[T, W, W]`` banded scatter per byte chunk. O(S·W) instead of
+    O(S·B), identical uint32 output bit-for-bit.
     """
     T, C = control.shape
     _, S = data.shape
@@ -161,7 +162,7 @@ def _banded_stream_routing(data, length, counts, *, W, S, B):
     of byte i is the number of flags strictly before i and the in-integer
     position has the Masked-VByte closed form (lengths ≤ 4 close it after
     three terms), so the chunked prefix + banded one-hot scatter of
-    ``banded.py`` finish the job exactly as in ``kernel.decode_tile``.
+    ``banded.py`` finish the job.
     """
     # integer starts via chunked prefix over the lengths (B axis, padded to
     # a chunk multiple; padding lengths are zero so starts stay == total)

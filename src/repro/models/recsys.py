@@ -385,7 +385,7 @@ def retrieval_scores_compressed(params, batch, cfg: RecSysConfig, *, top_k: int 
         if plan == "jnp" and fmt == "vbyte":
             plan = "ref"
     if (plan == "auto" and fmt == "vbyte"
-            and dispatch.default_plan().path != "pallas"):
+            and dispatch.default_plan(fmt).path != "pallas"):
         # off-TPU, ALL kinds keep the block-local ref decode (dot-score
         # kinds run it unfused: ref grid + dot_score as a second dispatch)
         plan = "ref"

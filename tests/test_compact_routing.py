@@ -1,10 +1,11 @@
-"""Masked-VByte compaction routing (``kernel.decode_tile`` with
-``chunk_width=None``): bit-exact against the gather oracle and the jnp
-decoder at the strides and block sizes the index uses, on the edge blocks
-(count 0, all 1-byte, all 5-byte, ragged tails), in differential mode with
-bases that wrap past 2^32; the compaction and prefix-count helpers alone on
-random inputs that meet their contracts; and a jaxpr check that the route
-issues no matmul."""
+"""Masked-VByte compaction routing (``kernel.decode_tile``), vbyte's one
+route: bit-exact against the gather oracle and the jnp decoder at the
+strides and block sizes the index uses and at the encoder's own strides, on
+the edge blocks (count 0, all 1-byte, all 5-byte, ragged tails), in
+differential mode with bases that wrap past 2^32, and fused into the
+epilogues; the compaction and prefix-count helpers alone on random inputs
+that meet their contracts; and a jaxpr check that the route issues no
+matmul."""
 import numpy as np
 import pytest
 
@@ -13,8 +14,9 @@ import jax.numpy as jnp
 
 from repro.core import CompressedIntArray
 from repro.core.vbyte import masked as vmasked
-from repro.kernels.vbyte_decode import (vbyte_decode_blocked,
+from repro.kernels.vbyte_decode import (dispatch, vbyte_decode_blocked,
                                         vbyte_decode_blocked_ref)
+from repro.kernels.vbyte_decode.dispatch import DecodePlan
 from repro.kernels.vbyte_decode.kernel import (_compact_left,
                                                _row_prefix_count, decode_tile,
                                                prefix_sum_tile)
@@ -50,7 +52,7 @@ def _assert_matches_oracles(payload, counts, bases=None, *, block_size,
     ref = np.asarray(vbyte_decode_blocked_ref(*args, **kw))
     np.testing.assert_array_equal(
         np.asarray(vmasked.decode_blocked(*args, **kw)), ref)
-    got = vbyte_decode_blocked(*args, chunk_width=None, interpret=True, **kw)
+    got = vbyte_decode_blocked(*args, interpret=True, **kw)
     np.testing.assert_array_equal(np.asarray(got), ref)
     if host:
         out, valid = _tile(args[0], args[1][:, None], block_size=block_size)
@@ -144,6 +146,75 @@ def test_compact_differential_bases_wrap(rng, stride):
     np.testing.assert_array_equal(ref, want.astype(np.uint32))
 
 
+# lists as the encoder lays them out (its own strides), each case one
+# (values, block size, encoder options): the block sizes, tight strides,
+# uniform-length blocks, ragged tails and both differential modes
+ENCODED = {
+    "block_size_8": lambda rng: (make_valid_stream(rng, 333), 8, {}),
+    "block_size_32": lambda rng: (make_valid_stream(rng, 333), 32, {}),
+    "block_size_64": lambda rng: (make_valid_stream(rng, 333), 64, {}),
+    "stride_multiple_8": lambda rng: (make_valid_stream(rng, 300), 64,
+                                      {"stride_multiple": 8}),
+    "all_five_byte": lambda rng: (np.full(257, 2**32 - 1, np.uint64), 128,
+                                  {}),
+    "all_one_byte": lambda rng: (np.zeros(300, np.uint64), 128, {}),
+    "three_bytes_and_up": lambda rng: (
+        make_valid_stream(rng, 400) | np.uint64(1 << 14), 128, {}),
+    **{f"ragged_{n}": (lambda rng, n=n: (make_valid_stream(rng, n), 128, {}))
+       for n in (1, 7, 129, 1000)},
+    "count_zero_blocks": lambda rng: (make_valid_stream(rng, 260), 128, {}),
+    "gaps": lambda rng: (make_valid_stream(rng, 777), 128, {}),
+    "differential": lambda rng: (
+        np.sort(rng.integers(0, 2**31, size=777)).astype(np.uint64), 128,
+        {"differential": True}),
+}
+
+
+@pytest.mark.parametrize("case", list(ENCODED))
+def test_compact_matches_oracles_at_encoder_strides(rng, case):
+    vals, block_size, enc = ENCODED[case](rng)
+    differential = enc.pop("differential", False)
+    ops = CompressedIntArray.encode(vals, block_size=block_size,
+                                    differential=differential,
+                                    **enc).device_operands()
+    payload, counts, bases = (np.asarray(ops[k])
+                              for k in ("payload", "counts", "bases"))
+    counts = counts.astype(np.int32)
+    if case == "count_zero_blocks":  # all-padding rows, as sharding pads
+        payload = np.pad(payload, ((0, 2), (0, 0)))
+        counts = np.pad(counts, (0, 2))
+        bases = np.pad(bases, (0, 2))
+    ref = _assert_matches_oracles(payload, counts, bases,
+                                  block_size=block_size,
+                                  differential=differential, host=True)
+    live = np.arange(block_size)[None, :] < counts[:, None]
+    np.testing.assert_array_equal(ref[live], vals.astype(np.uint32))
+
+
+@pytest.mark.parametrize("epilogue", ["bag_sum", "dot_score",
+                                      "adjacency_rebase"])
+def test_compact_fused_epilogues_parity(rng, epilogue):
+    # the fused Pallas kernels on the compaction core equal the fused jnp
+    # program and the unfused reference, bit for bit
+    vals = np.sort(rng.integers(0, 2048, size=300)).astype(np.uint64)
+    arr = CompressedIntArray.encode(vals, differential=True)
+    table = jnp.asarray(rng.standard_normal((2048, 8)).astype(np.float32))
+    extras = {
+        "bag_sum": {"table": table},
+        "dot_score": {"table": table, "query": jnp.asarray(
+            rng.standard_normal((1, 8)).astype(np.float32))},
+        "adjacency_rebase": {"edge_base": jnp.asarray(
+            rng.integers(0, 2048, (arr.n_blocks, 128)).astype(np.int32))},
+    }[epilogue]
+    outs = [jax.tree_util.tree_leaves(dispatch.decode(
+        arr, epilogue=epilogue, epilogue_operands=extras, plan=plan))
+        for plan in (DecodePlan("pallas", True, 8), DecodePlan("jnp", True),
+                     "unfused")]
+    for other in outs[1:]:
+        for x, y in zip(outs[0], other):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 # ---------------------------------------------------------------------------
 # the helpers alone
 # ---------------------------------------------------------------------------
@@ -200,7 +271,6 @@ def test_compact_route_issues_no_matmul(rng):
     epi = str(jax.make_jaxpr(prefix_sum_tile)(
         out, out > 0, jnp.zeros((8, 1), jnp.int32)))
     kern = str(jax.make_jaxpr(lambda p, c, b: vbyte_decode_blocked(
-        p, c, b, block_size=128, differential=True, chunk_width=None,
-        interpret=True))(p, c, jnp.zeros(len(counts), jnp.uint32)))
+        p, c, b, block_size=128, differential=True, interpret=True))(p, c, jnp.zeros(len(counts), jnp.uint32)))
     assert "vbyte_decode_compact" in kern
     assert kern.count("dot_general") == epi.count("dot_general") > 0

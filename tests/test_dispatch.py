@@ -1,8 +1,7 @@
-"""Dispatch layer: plan resolution, the persisted autotune cache, the
+"""Dispatch layer: plan resolution and the default route table, the
 counts/bases shape contract at the ops boundary, the spans of a traced
 call, and the names of the Pallas kernels it launches."""
 import itertools
-import json
 
 import numpy as np
 import pytest
@@ -12,7 +11,8 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core import CompressedIntArray
-from repro.kernels.vbyte_decode import dispatch, normalize_block_meta
+from repro.kernels.vbyte_decode import (dispatch, epilogues,
+                                        normalize_block_meta)
 from repro.kernels.vbyte_decode.banded import kernel_name
 from repro.kernels.vbyte_decode.dispatch import DecodePlan
 
@@ -66,17 +66,70 @@ def test_decode_rejects_wrong_length_counts(rng):
 # plan resolution
 # ---------------------------------------------------------------------------
 def test_resolve_plan_aliases():
-    kw = dict(format="vbyte", epilogue="bag_sum", block_size=128)
+    kw = dict(format="vbyte", block_size=128)
+    default = dispatch.default_plan("vbyte")
+    assert dispatch.resolve_plan("auto", **kw) == default
+    assert dispatch.resolve_plan(None, **kw) == default
     assert dispatch.resolve_plan("kernel", **kw) == DecodePlan("pallas", True)
     assert dispatch.resolve_plan("jnp", **kw) == DecodePlan("jnp", True)
-    assert dispatch.resolve_plan("unfused", **kw).fused is False
-    assert dispatch.resolve_plan("fused", **kw).fused is True
+    assert dispatch.resolve_plan("ref", **kw) == DecodePlan("ref", False)
+    assert dispatch.resolve_plan("unfused", **kw) == DecodePlan(
+        default.path, False)
+    assert dispatch.resolve_plan("fused", **kw) == DecodePlan(
+        default.path, True)
     custom = DecodePlan("pallas", False, 16)
     assert dispatch.resolve_plan(custom, **kw) is custom
-    with pytest.raises(ValueError, match="unknown plan"):
-        dispatch.resolve_plan("warp-speed", **kw)
+    # the plan strings with no caller are gone
+    for gone in ("warp-speed", "pallas", "banded", "dense"):
+        with pytest.raises(ValueError, match="unknown plan"):
+            dispatch.resolve_plan(gone, **kw)
     with pytest.raises(ValueError, match="unknown plan path"):
         DecodePlan("cuda", True)
+
+
+# the route each format runs on a TPU: the plans of the benchmark's cells
+TPU_LABELS = {"vbyte": "pallas_fused_bt8",
+              "streamvbyte": "pallas_fused_bt8_w32",
+              "binpack": "pallas_fused_bt8"}
+
+
+@pytest.mark.parametrize("fmt", ["vbyte", "streamvbyte", "binpack"])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_auto_plan_is_the_default_table(monkeypatch, backend, fmt):
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: backend)
+    want = (DecodePlan("pallas", True, 8,
+                       chunk=32 if fmt == "streamvbyte" else None)
+            if backend == "tpu" else DecodePlan("jnp", True))
+    assert dispatch.default_plan(fmt) == want
+    plan = dispatch.resolve_plan("auto", format=fmt, block_size=128)
+    assert plan == want
+    if backend == "tpu":
+        assert plan.label == TPU_LABELS[fmt]
+    # a chunk wider than the block is clamped to it
+    small = dispatch.resolve_plan("auto", format=fmt, block_size=16)
+    assert small.chunk == (16 if want.chunk else None)
+
+
+@pytest.mark.parametrize("fmt,path", [("vbyte", "pallas"),
+                                      ("binpack", "pallas"),
+                                      ("vbyte", "jnp"),
+                                      ("streamvbyte", "jnp"),
+                                      ("vbyte", "ref")])
+def test_a_chunk_on_a_route_without_one_raises(rng, fmt, path):
+    """Only Stream VByte's Pallas cores have a chunked route; a plan that
+    sets a chunk anywhere else is refused when it is resolved."""
+    plan = DecodePlan(path, path == "pallas", chunk=32)
+    with pytest.raises(ValueError, match="chunked route"):
+        dispatch.resolve_plan(plan, format=fmt, block_size=128)
+    vals = np.sort(rng.integers(0, 2**20, 200)).astype(np.uint64)
+    arr = CompressedIntArray.encode(vals, format=fmt, differential=True)
+    with pytest.raises(ValueError, match="chunked route"):
+        dispatch.decode(arr, plan=plan)
+    if path == "pallas":  # nor can the fused kernel builder make one
+        with pytest.raises(ValueError, match="chunked route"):
+            epilogues.fused_decode(arr.device_operands(), {}, format=fmt,
+                                   epilogue="stream", block_size=128,
+                                   differential=True, chunk_width=32)
 
 
 def test_epilogue_operand_validation(rng):
@@ -96,76 +149,10 @@ def test_epilogue_operand_validation(rng):
                                                                  jnp.int32)})
 
 
-# ---------------------------------------------------------------------------
-# measured autotune cache
-# ---------------------------------------------------------------------------
-def test_autotune_persists_and_auto_plan_reads_cache(tmp_path, monkeypatch):
-    cache_file = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache_file))
-    cache = dispatch.autotune(
-        formats=("vbyte",), epilogue_names=("bag_sum",), block_size=32,
-        n_blocks=8, vocab=256, d=8, reps=1, warmup=1,
-        cache_file=str(cache_file))
-    key = dispatch.cache_key("vbyte", "bag_sum", 32)
-    assert key in cache and "plan" in cache[key]
-    on_disk = json.loads(cache_file.read_text())
-    assert on_disk[key]["candidates_ms"]
-
-    # "auto" resolves to the measured best, not the heuristic default
-    dispatch.load_cache(str(cache_file), reload=True)
-    plan = dispatch.resolve_plan("auto", format="vbyte", epilogue="bag_sum",
-                                 block_size=32)
-    assert plan == DecodePlan(**on_disk[key]["plan"])
-    # unmeasured workloads fall back to the heuristic
-    fallback = dispatch.resolve_plan("auto", format="streamvbyte",
-                                     epilogue="dot_score", block_size=32)
-    expected = dispatch.default_plan("dot_score", "streamvbyte")
-    assert fallback == dispatch.replace(
-        expected, chunk=dispatch._clamp_chunk(expected.chunk, 32))
-    dispatch.load_cache(reload=True)  # restore global cache state
-
-
-def test_cache_migration_drops_stale_schema_entries(tmp_path, monkeypatch):
-    """A two-format-era cache (no per-entry schema tag, or an old one) must
-    be invalidated on load: stale plans were measured before binpack joined
-    the format registry and can resolve to a plan shape that no longer
-    matches the codec (e.g. a banded chunk for a format with no length
-    scan). Every stale entry falls back to the heuristic default."""
-    cache_file = tmp_path / "autotune.json"
-    key = dispatch.cache_key("vbyte", "bag_sum", 32)
-    old_key = dispatch.cache_key("streamvbyte", "dot_score", 32)
-    cache_file.write_text(json.dumps({
-        key: {"plan": {"path": "jnp", "fused": False, "chunk": 64}},
-        old_key: {"schema": 1,
-                  "plan": {"path": "pallas", "fused": True, "chunk": 64}},
-        "garbage": "not-a-dict",
-    }))
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache_file))
-    cache = dispatch.load_cache(str(cache_file), reload=True)
-    assert cache == {}  # versionless + old-schema + junk all dropped
-
-    for fmt, epi in (("vbyte", "bag_sum"), ("streamvbyte", "dot_score"),
-                     ("binpack", "bag_sum")):
-        plan = dispatch.resolve_plan("auto", format=fmt, epilogue=epi,
-                                     block_size=32)
-        expected = dispatch.default_plan(epi, fmt)
-        assert plan == dispatch.replace(
-            expected, chunk=dispatch._clamp_chunk(expected.chunk, 32))
-
-    # current-schema entries survive the same migration pass untouched
-    good = {"schema": dispatch.CACHE_SCHEMA,
-            "plan": {"path": "jnp", "fused": True, "chunk": None},
-            "candidates_ms": {}}
-    cache_file.write_text(json.dumps({key: good, old_key: {"schema": 0}}))
-    cache = dispatch.load_cache(str(cache_file), reload=True)
-    assert cache == {key: good}
-    dispatch.load_cache(reload=True)  # restore global cache state
-
-
 def test_auto_plan_decodes_correctly(rng):
-    """End to end: plan='auto' (whatever the cache says) is bit-correct."""
+    """End to end: plan='auto' (the default route) is bit-correct."""
     vals = np.sort(rng.integers(0, 512, 100)).astype(np.uint64)
-    for fmt in ("vbyte", "streamvbyte"):
+    for fmt in ("vbyte", "streamvbyte", "binpack"):
         arr = CompressedIntArray.encode(vals, format=fmt, block_size=32,
                                         differential=True)
         out = arr.decode(plan="auto")
@@ -208,8 +195,7 @@ def test_decode_span_covers_prepare_then_launch(rng, plan,
     a0, a1 = _ends(prepare)
     b0, b1 = _ends(launch)
     assert p0 < a0 < a1 < b0 < b1 < p1  # inside the parent, no overlap
-    p = dispatch.resolve_plan(plan, format="vbyte", epilogue="stream",
-                              block_size=128)
+    p = dispatch.resolve_plan(plan, format="vbyte", block_size=128)
     assert parent["attrs"] == {"format": "vbyte", "plan": p.label,
                                "epilogue": "stream", "blocks": arr.n_blocks,
                                "chunk": p.chunk, "sharded": False}
@@ -239,19 +225,20 @@ def test_decode_refused_in_prepare_records_no_launch(rng,
 # kernel names
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("fmt,chunk,epilogue,want", [
-    ("vbyte", 64, None, "vbyte_decode_banded_w64"),
+    ("streamvbyte", 64, None, "streamvbyte_decode_banded_w64"),
     ("vbyte", None, None, "vbyte_decode_compact"),
     ("streamvbyte", 32, None, "streamvbyte_decode_banded_w32"),
     ("binpack", None, None, "binpack_decode_gather"),
-    ("vbyte", 64, "bag_sum", "vbyte_fused_bag_sum_banded_w64"),
+    ("streamvbyte", 32, "bag_sum", "streamvbyte_fused_bag_sum_banded_w32"),
     ("binpack", None, "membership", "binpack_fused_membership_gather"),
     ("vbyte", None, "membership", "vbyte_fused_membership_compact"),
     ("streamvbyte", None, None, "streamvbyte_decode_dense"),
 ])
 def test_pallas_call_carries_kernel_name(rng, fmt, chunk, epilogue, want):
     """Each decode ``pallas_call`` is named for its format, its fused
-    epilogue and its routing core; binpack ignores the chunk width, and
-    the unchunked core is vbyte's compaction and streamvbyte's dense one."""
+    epilogue and its routing core: vbyte's compaction, binpack's gather
+    (whatever chunk width the name is asked for), and streamvbyte's dense
+    or banded core."""
     vals = np.sort(rng.integers(0, 512, 64)).astype(np.uint64)
     arr = CompressedIntArray.encode(vals, format=fmt, block_size=128,
                                     differential=True)

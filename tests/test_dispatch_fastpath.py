@@ -67,11 +67,11 @@ def _results(tele):
 
 @pytest.fixture
 def tele():
-    dispatch._invalidate_launches()
+    dispatch._LAUNCHES.clear()
     t = obs.Telemetry()
     with obs.install(t):
         yield t
-    dispatch._invalidate_launches()
+    dispatch._LAUNCHES.clear()
 
 
 def _assert_same(a, b):
@@ -144,7 +144,7 @@ def _variants():
         ("meta_column", lambda: dispatch.decode(column, **raw)),
         ("plan", lambda: dispatch.decode(base, plan="jnp")),
         ("plan_object", lambda: dispatch.decode(
-            base, plan=DecodePlan("jnp", True, chunk=8))),
+            base, plan=DecodePlan("jnp", False))),
         ("epilogue", lambda: dispatch.decode(base, epilogue="checksum")),
         ("extras", lambda: dispatch.decode(
             base, epilogue="bag_sum", epilogue_operands=_extras("bag_sum"))),
@@ -214,54 +214,6 @@ def test_numpy_extra_bypasses(tele):
         dispatch.decode(arr, epilogue="bag_sum",
                         epilogue_operands={"table": table})
     assert _results(tele) == {"hit": 0, "miss": 0, "bypass": 2}
-
-
-def test_reloading_the_plan_cache_invalidates_entries(tele):
-    _, arr = _list(11)
-    dispatch.decode(arr)
-    dispatch.decode(arr)
-    dispatch.load_cache(reload=True)
-    dispatch.decode(arr)
-    dispatch.decode(arr)
-    assert _results(tele) == {"hit": 2, "miss": 2, "bypass": 0}
-
-
-def test_autotune_invalidates_entries(tele, tmp_path, monkeypatch):
-    cache_file = tmp_path / "autotune.json"
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache_file))
-    dispatch.load_cache()  # the cache the environment names, loaded first
-    _, arr = _list(12)
-    kw = dict(epilogue="bag_sum", epilogue_operands=_extras("bag_sum"))
-    dispatch.decode(arr, **kw)
-    dispatch.decode(arr, **kw)
-    before = _results(tele)
-    assert (before["hit"], before["miss"]) == (1, 1)
-    dispatch.autotune(formats=("vbyte",), epilogue_names=("bag_sum",),
-                      block_size=32, n_blocks=8, vocab=256, d=8, reps=1,
-                      warmup=1, cache_file=str(cache_file))
-    after = _results(tele)
-    dispatch.decode(arr, **kw)  # the measured plan may differ: a miss
-    assert _results(tele)["miss"] == after["miss"] + 1
-    # the new entry runs the measured plan
-    want = DecodePlan(**dispatch.load_cache()[dispatch.cache_key(
-        "vbyte", "bag_sum", 32)]["plan"])
-    with obs.install(obs.Telemetry()) as t:
-        dispatch.decode(arr, **kw)
-    (span,) = [s for s in t.tracer.spans if s["name"] == "decode"]
-    assert span["attrs"]["plan"] == want.label
-    monkeypatch.undo()
-    dispatch.load_cache(reload=True)
-
-
-def test_the_autotune_cache_path_is_part_of_the_key(tele, tmp_path,
-                                                    monkeypatch):
-    _, arr = _list(13)
-    dispatch.decode(arr)
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "none.json"))
-    dispatch.decode(arr)
-    assert _results(tele) == {"hit": 0, "miss": 2, "bypass": 0}
-    monkeypatch.undo()
-    dispatch.load_cache(reload=True)
 
 
 def _malformed():
@@ -384,12 +336,12 @@ def test_hit_keeps_spans_and_call_counter(tele):
 
 
 def test_counter_is_silent_without_telemetry():
-    dispatch._invalidate_launches()
+    dispatch._LAUNCHES.clear()
     _, arr = _list(19)
     dispatch.decode(arr)
     dispatch.decode(arr)
     assert obs.installed() is None
-    dispatch._invalidate_launches()
+    dispatch._LAUNCHES.clear()
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,

@@ -390,6 +390,7 @@ def test_topk_bit_identical_with_and_without_telemetry(rng):
 def test_serve_counters_mirror_serve_stats(rng):
     """SearchEngine keeps the serve_stats dict API and mirrors increments
     into labeled registry counters."""
+    from repro.kernels.vbyte_decode import dispatch
     from repro.launch.serve import SearchEngine
 
     index = _small_index(rng)
@@ -403,7 +404,11 @@ def test_serve_counters_mirror_serve_stats(rng):
     assert m[key]["value"] == 1
     assert m['serve_requests_total{engine=search,mode=topk}']["value"] == 1
     assert any(k.startswith("decode_calls_total") for k in m)
-    assert any(k.startswith("plan_cache_total") for k in m)
+    # every call ran the default route of its term's format
+    labels = {dispatch.default_plan(f).label
+              for f in ("vbyte", "streamvbyte", "binpack")}
+    assert any(k.startswith("decode_calls_total")
+               and any(f"plan={label}" in k for label in labels) for k in m)
 
 
 def test_wal_and_recovery_metrics(tmp_path, rng):

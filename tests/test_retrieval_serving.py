@@ -108,7 +108,7 @@ def test_chunked_table_build_matches_one_shot(cfg, params, chunk_rows, dtype):
     np.testing.assert_array_equal(np.asarray(chunked), np.asarray(one_shot))
 
 
-@pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("vbyte", 32),
+@pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("streamvbyte", None),
                                        ("streamvbyte", 32),
                                        ("binpack", None)])
 @pytest.mark.parametrize("n_queries", [1, 8])

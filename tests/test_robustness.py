@@ -32,7 +32,7 @@ from repro.robustness import faultgen
 from repro.robustness.validate import expected_checksums
 
 FORMATS = ("vbyte", "streamvbyte", "binpack")
-PLANS = ("jnp", "banded")  # the vectorized grid plans (dense + banded)
+PLANS = ("jnp", "kernel")  # the grid plans: jnp decoder, Pallas tile core
 SEEDS = (0, 1, 2)
 
 

@@ -63,9 +63,8 @@ def tpu_plan(monkeypatch):
     """Resolve ``plan="auto"`` as a TPU process would."""
     monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
 
-    def resolve(fmt, epilogue="stream"):
-        return dispatch.resolve_plan("auto", format=fmt, epilogue=epilogue,
-                                     block_size=B)
+    def resolve(fmt):
+        return dispatch.resolve_plan("auto", format=fmt, block_size=B)
     return resolve
 
 
@@ -92,6 +91,12 @@ def _meta(sharding):
             "bases": _spec(sharding, (N_BLOCKS,), jnp.uint32)}
 
 
+def _chunk(chunk):
+    """The chunk keyword of a decode call: only Stream VByte's cores have
+    one."""
+    return {} if chunk is None else {"chunk_width": chunk}
+
+
 def _assert_mosaic_kernel(lowered):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
@@ -109,7 +114,7 @@ def test_default_decode_plan_compiles(one_chip, tpu_plan, fmt):
     operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
     lowered = DECODE_FNS[fmt].lower(
         **operands, block_size=B, differential=True,
-        block_tile=plan.block_tile, chunk_width=plan.chunk, interpret=False)
+        block_tile=plan.block_tile, interpret=False, **_chunk(plan.chunk))
     _assert_mosaic_kernel(lowered)
 
 
@@ -120,7 +125,7 @@ def test_default_vbyte_plan_compiles_at_cell_stride(one_chip, tpu_plan):
                 **_meta(one_chip)}
     lowered = ops.vbyte_decode_blocked.lower(
         **operands, block_size=B, differential=True,
-        block_tile=plan.block_tile, chunk_width=plan.chunk, interpret=False)
+        block_tile=plan.block_tile, interpret=False)
     _assert_mosaic_kernel(lowered)
 
 
@@ -141,16 +146,17 @@ def test_launch_cache_program_compiles_at_cell_stride(one_chip, tpu_plan,
     _assert_mosaic_kernel(fn.lower(*leaves))
 
 
-@pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("vbyte", 32),
-                                       ("streamvbyte", None), ("vbyte", 64)])
+@pytest.mark.parametrize("fmt,chunk", [("vbyte", None), ("streamvbyte", 16),
+                                       ("streamvbyte", None),
+                                       ("streamvbyte", 64)])
 def test_other_routing_widths_compile(one_chip, fmt, chunk):
     # the unchunked cores (vbyte compaction, streamvbyte dense) decode
-    # every impact stream in the weighted epilogues; the banded widths are
-    # vbyte's A/B baseline (plan="banded") and autotune candidates
+    # every impact stream in the weighted epilogues; the other banded
+    # widths are what a DecodePlan(..., chunk=W) runs
     operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
     lowered = DECODE_FNS[fmt].lower(
         **operands, block_size=B, differential=True, block_tile=8,
-        chunk_width=chunk, interpret=False)
+        interpret=False, **_chunk(chunk))
     _assert_mosaic_kernel(lowered)
 
 
@@ -175,7 +181,7 @@ def _search_extras(sharding, fmt, epilogue):
                                       "bm25_accum", "bm25_weighted"])
 @pytest.mark.parametrize("fmt", ["vbyte", "binpack"])
 def test_fused_search_epilogue_compiles(one_chip, tpu_plan, fmt, epilogue):
-    plan = tpu_plan(fmt, epilogue)
+    plan = tpu_plan(fmt)
     assert plan.path == "pallas" and plan.fused, plan
     operands = {**_format_operands(one_chip, fmt), **_meta(one_chip)}
     lowered = epilogues.fused_decode.lower(
@@ -206,7 +212,7 @@ def test_sharded_search_decode_compiles(topo, tpu_plan, epilogue):
         extras["probe"] = _spec(NamedSharding(mesh, P()), (1, 512), jnp.int32)
     if epilogue == "bm25_weighted":
         extras["w_payload"] = _spec(blk, (N_BLOCKS, W_STRIDE), jnp.uint8)
-    plan = tpu_plan("vbyte", epilogue)
+    plan = tpu_plan("vbyte")
     fn = dispatch._build_sharded_fn(
         mesh, ("data",), "vbyte", epilogue, B, True, plan, False, False,
         tuple(sorted(extras)))
@@ -228,7 +234,7 @@ def test_dot_score_hbm_gather_compiles(one_chip, tpu_plan, table_dtype, nq):
     # them at dynamic offsets, and XLA must pass the table to the
     # kernel as it is, with no copy or relayout of it
     d = 256
-    plan = tpu_plan("vbyte", "dot_score")
+    plan = tpu_plan("vbyte")
     assert plan.path == "pallas" and plan.fused, plan
     w = d // 2 if table_dtype == jnp.bfloat16 else d
     table = _spec(one_chip, (RETRIEVAL_ROWS, w), jnp.uint32)
